@@ -13,12 +13,13 @@ re-materialized per client:
   feature dim, int8 values + int32 indices (5 bytes per kept entry), same
   absmax scale. Wire cost ``~1.25 * top_k`` of dense float32.
 
-Every leaf is handled in the canonical kernel layout (U, L_leaf, F):
+Every leaf is handled in the canonical wire layout (U, L_leaf, F):
 stacked-layer leaves (layer ids of shape (L,)) flatten trailing dims to F;
 whole-tensor leaves are L_leaf = 1. Aggregation folds the Eq. 5 coefficient
 ``c[u, l]`` INTO the dequant scale, so dequantize + weight + accumulate is
 one pass — pure-jnp einsum / scatter-add, or the fused Pallas
-``kernels.adel_agg_q8`` when ``agg_impl="pallas"`` (interpret mode on CPU).
+``kernels.adel_agg_q8`` (which reads the payload layer-major) when
+``agg_impl="pallas"`` (interpret mode on CPU).
 
 The payload crossing the jit/device boundary is a flat list (params-tree
 flatten order) of per-leaf tuples ``(q, scale)`` or ``(q, scale, idx)`` —
@@ -148,8 +149,10 @@ def _agg_leaf(entry, param, ids, c, cfg: CompressionConfig,
         return out.reshape(shape)
     q, scale = entry
     if agg_impl == "pallas":
+        # the kernel is layer-major: (Ll, U, F) payload, (Ll, U) weights
         from repro.kernels.adel_agg import adel_agg_q8
-        out = adel_agg_q8(q, scale, w, interpret=interpret)
+        out = adel_agg_q8(jnp.swapaxes(q, 0, 1), scale.T, w.T,
+                          interpret=interpret)
     else:
         out = jnp.einsum("ul,ulf->lf", w * scale, q.astype(jnp.float32))
     return out.reshape(shape)
@@ -172,7 +175,7 @@ def aggregate_compressed(payload: list, params: PyTree, layer_ids: PyTree,
     cohort-global coefficients). ``params`` is used for leaf shapes only.
     """
     from repro.core.aggregation import layer_coefficients
-    if interpret is None:
+    if interpret is None and agg_impl == "pallas":
         from repro.kernels.ops import default_interpret
         interpret = default_interpret()
     if coeffs is None:

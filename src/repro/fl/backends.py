@@ -106,10 +106,6 @@ from repro.fl.client import batched_client_deltas, local_update
 # back-compat: `from repro.fl.backends import BACKENDS` keeps working)
 from repro.fl.spec import AGG_IMPLS, BACKENDS, ExecSpec
 
-try:                                     # jax >= 0.5
-    from jax import shard_map as _shard_map
-except ImportError:                      # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["BACKENDS", "AGG_IMPLS", "ExecSpec", "ExecutionBackend",
@@ -573,11 +569,11 @@ class ShardMapBackend(ExecutionBackend):
             spec_c = P(ax)      # leading client axis sharded over batch axes
             spec_r = P()        # replicated
             wm_spec = spec_c if hetero else spec_r
-            self._steps[key] = jax.jit(_shard_map(
+            self._steps[key] = jax.jit(jax.shard_map(
                 local_fn, mesh=mesh,
                 in_specs=(spec_r, spec_c, spec_c, spec_c, spec_c, spec_r,
                           spec_r, wm_spec),
-                out_specs=spec_r, check_rep=False),
+                out_specs=spec_r, check_vma=False),
                 donate_argnums=self._donate_params)
         return self._steps[key]
 

@@ -271,29 +271,11 @@ class ExecSpec:
                             "t's device step and AOT-warms the round/eval "
                             "steps before round 0 (trajectories stay "
                             "bit-identical to serial)")
-        g.add_argument("--compile-cache", default=None, metavar="DIR",
-                       help="enable jax's persistent compilation cache at "
-                            "DIR (jax_compilation_cache_dir); compiled "
-                            "round/eval steps survive process restarts")
 
     @classmethod
     def from_cli(cls, args, *, base: Optional["ExecSpec"] = None,
                  strict: Optional[bool] = None) -> "ExecSpec":
-        """Resolve the spec from parsed :meth:`add_cli_args` flags.
-
-        Also applies the ``--compile-cache DIR`` side flag: it configures
-        the jax process (persistent compilation cache), not the spec, so it
-        lives here rather than as an ExecSpec field.
-        """
-        cache_dir = getattr(args, "compile_cache", None)
-        if cache_dir:
-            import jax
-            os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            # cache everything: by default jax skips "fast to compile"
-            # computations, which is most of a CPU smoke run
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
+        """Resolve the spec from parsed :meth:`add_cli_args` flags."""
         compression = None
         if args.compression is not None:
             compression = (args.compression if args.topk_frac is None

@@ -26,6 +26,7 @@ from repro.core.replan import TRIGGERS
 from repro.data.synthetic import make_image_dataset
 from repro.fleet.engine import partition_fleet, run_fleet
 from repro.fleet.population import PopulationSpec
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.paper_models import make_cnn, make_mlp
 
 __all__ = ["Scenario", "SCENARIOS", "get_scenario", "run_scenario"]
@@ -311,6 +312,7 @@ def main(argv=None) -> None:
                          "fleet_scenarios.json for benchmarks.report")
     ap.add_argument("--quiet", action="store_true")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.list or not args.run:
         print(f"{'scenario':38s} {'fleet':28s} {'avail':10s} "

@@ -4,9 +4,14 @@ pure-jnp oracle in ref.py and a model-layout wrapper in ops.py:
 * flash_attention — GQA/causal/sliding-window online-softmax attention
   (prefill/train hot-spot of the dense/moe/vlm/hybrid archs).
 * ssd_scan — Mamba2 SSD chunk scan with VMEM-carried state (ssm/hybrid).
-* adel_agg — the paper's layer-wise masked aggregation (server hot loop).
+* adel_agg / adel_agg_q8 — the paper's layer-wise masked aggregation
+  (server hot loop), over float and over int8 wire payloads.
 
-Validated in interpret=True mode on CPU; compiled for TPU on real hardware.
+All are checked against their oracles in interpret mode on CPU. The two
+aggregation kernels are also compiled for a described TPU v5e by
+tests/test_tpu_compile.py, and chip_smoke.py runs them on the chip inside
+the temporal round step. flash_attention and ssd_scan are on no model
+path and have only been run in interpret mode.
 """
 from repro.kernels.adel_agg import adel_agg
 from repro.kernels.flash_attention import flash_attention

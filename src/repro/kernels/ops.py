@@ -1,11 +1,11 @@
 """jit'd wrappers bridging model-layout tensors to the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False on TPU;
-each wrapper reshapes from model layout to kernel layout and back, and is
-drop-in compatible with the pure-jnp path it accelerates.
+``interpret`` defaults to True on the CPU backend and False on TPU; any
+other backend raises, so a kernel never silently runs interpreted on an
+accelerator. Each wrapper reshapes from model layout to kernel layout and
+back, and is drop-in compatible with the pure-jnp path it accelerates.
 """
 from __future__ import annotations
-
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +19,14 @@ __all__ = ["gqa_flash", "ssd_chunked_pallas", "adel_aggregate_pallas",
 
 
 def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret the kernels on CPU, compile them on TPU, refuse the rest."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"the Pallas kernels target TPU (compiled) or CPU "
+                       f"(interpreted); the default backend is {backend!r}")
 
 
 def gqa_flash(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -84,12 +91,10 @@ def adel_aggregate_pallas(grads, layer_ids_tree, mask, p, *,
             return jnp.tensordot(w, g.astype(jnp.float32),
                                  axes=(0, 0)).astype(g.dtype)
         L = g.shape[1]
-        F = 1
-        for d in g.shape[2:]:
-            F *= d
-        flat = g.reshape(U, L, F)
-        cl = jnp.take(c, ids, axis=1)              # (U, L)
-        # adel_agg pads F to a block multiple internally
+        # the kernel is layer-major: (L, U, F) grads, (L, U) coefficients;
+        # it pads F to a block multiple internally
+        flat = jnp.swapaxes(g.reshape(U, L, -1), 0, 1)
+        cl = jnp.take(c, ids, axis=1).T            # (L, U)
         out = adel_agg(flat, cl, interpret=interpret)
         return out.reshape(g.shape[1:]).astype(g.dtype)
 

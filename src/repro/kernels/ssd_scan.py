@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 __all__ = ["ssd_scan"]
 
 
@@ -98,7 +96,7 @@ def ssd_scan(xdt: jnp.ndarray, la: jnp.ndarray, b: jnp.ndarray,
         out_specs=pl.BlockSpec((1, Q, P), m3),
         out_shape=jax.ShapeDtypeStruct((BH, S, P), xdt.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xdt, la, b, c)

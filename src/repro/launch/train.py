@@ -15,8 +15,12 @@ execution surface is one :class:`repro.fl.spec.ExecSpec` (``exec=`` /
 the shared ``--backend/--compression/--lam/...`` CLI group).
 Checkpointing rides the runtime's ``on_round`` hook.
 
-On the CPU container use --reduced (default); the full configs are
-exercised via dryrun.py.
+``--reduced`` (the default) trains the CPU toy of the config, with every
+width cut (:meth:`repro.configs.base.ArchConfig.reduced`). ``--full`` trains
+the config as the registry gives it: for ``qwen1.5-4b`` that is one TPU v5e
+chip's share of the model at its published widths (4 layers, 1/8 of the
+vocabulary; :mod:`repro.configs.qwen1_5_4b`), which fits one chip on the
+``temporal`` backend. ``chip_smoke.py`` at the repository root runs it.
 
     PYTHONPATH=src python -m repro.launch.train --arch hymba-1.5b \
         --method adel --rounds 60 --tmax 240 --backend temporal
@@ -42,6 +46,7 @@ from repro.fl.runtime import History, RoundRuntime, probe_s_max
 from repro.fl.spec import ExecSpec
 from repro.fl.tasks import lm_task
 from repro.fleet.population import PopulationSpec
+from repro.launch.compile_cache import use_compile_cache
 
 
 def run_training(arch: str, *, method: str = "adel", rounds: int = 40,
@@ -140,10 +145,11 @@ def run_training(arch: str, *, method: str = "adel", rounds: int = 40,
                                          and solver_steps) else {}
         schedule = solve(acfg, solver, **kw)
     policy = make_policy(method, acfg, schedule=schedule)
-    # the minibatch pad width prices EVERY client's round compute at
-    # O(s_max) sequences, so cap it: larger planned batches are clipped by
-    # the sampler (only the straggler clock keeps the full B3 batch) —
-    # raise s_max_cap on real accelerators
+    # the minibatch pad width prices EVERY client's round compute and
+    # activation memory at O(s_max) sequences, so cap it: larger planned
+    # batches are clipped by the sampler (only the straggler clock keeps
+    # the full B3 batch). At the qwen1.5-4b chip share with seq=512 a cap
+    # of 8 fits one v5e on the temporal backend; 32 does not
     s_max = max(min(probe_s_max(policy, rounds), s_max_cap,
                     4 * task.n_per_client), 2)
 
@@ -173,35 +179,6 @@ def run_training(arch: str, *, method: str = "adel", rounds: int = 40,
     return params, hist
 
 
-@contextlib.contextmanager
-def _profile(trace_dir: str | None):
-    """Opt-in ``jax.profiler`` device trace around the training run.
-
-    Best-effort: some CPU-only / stripped builds lack a working profiler
-    backend, and a missing trace must never kill a training run — failures
-    downgrade to a warning.
-    """
-    if not trace_dir:
-        yield
-        return
-    started = False
-    try:
-        jax.profiler.start_trace(trace_dir)
-        started = True
-    except Exception as e:  # pragma: no cover - backend-dependent
-        print(f"[train] jax.profiler unavailable ({e}); continuing "
-              f"without a device trace")
-    try:
-        yield
-    finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-                print(f"[train] device trace -> {trace_dir}")
-            except Exception as e:  # pragma: no cover - backend-dependent
-                print(f"[train] jax.profiler.stop_trace failed ({e})")
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="hymba-1.5b")
@@ -214,9 +191,11 @@ def main(argv=None):
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--reduced", action="store_true", default=True,
-                    help="reduced arch for the CPU container (default)")
+                    help="the config's CPU toy, every width cut "
+                         "(default)")
     ap.add_argument("--full", dest="reduced", action="store_false",
-                    help="use the full (non-reduced) config — TPU only")
+                    help="the config as registered, at published widths "
+                         "(qwen1.5-4b: one v5e chip's share) — TPU only")
     ap.add_argument("--replan", default=None, choices=list(TRIGGERS),
                     help="online re-planning trigger (repro.core.replan)")
     ap.add_argument("--replan-every", type=int, default=None,
@@ -240,9 +219,9 @@ def main(argv=None):
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
                     help="capture a jax.profiler device trace of the whole "
                          "run into DIR (view with TensorBoard / Perfetto); "
-                         "opt-in — profiling is skipped with a warning if "
-                         "the profiler backend is unavailable")
+                         "a profiler that fails fails the run")
     args = ap.parse_args(argv)
+    use_compile_cache()
     replan = args.replan
     if replan is not None and args.replan_every is not None:
         replan = ReplanConfig(trigger=replan, every=args.replan_every)
@@ -253,7 +232,9 @@ def main(argv=None):
              if any(v is not None for v in pop_flags) else None)
     tracer = obs.make_tracer(args.events)
     t0 = obs.now()
-    with _profile(args.profile_dir):
+    profile = (jax.profiler.trace(args.profile_dir) if args.profile_dir
+               else contextlib.nullcontext())
+    with profile:
         _, hist = run_training(args.arch, method=args.method,
                                rounds=args.rounds,
                                tmax=args.tmax, U=args.clients, eta0=args.eta0,
@@ -262,6 +243,8 @@ def main(argv=None):
                                exec=spec, replan=replan, population=pspec,
                                ckpt=args.ckpt, tracer=tracer)
     tracer.close()
+    if args.profile_dir:
+        print(f"[train] device trace -> {args.profile_dir}")
     loss = hist.train_loss[-1]
     print(f"[train] done in {obs.now() - t0:.1f}s wall; "
           f"final token loss {loss:.4f} (ppl {math.exp(min(loss, 30)):.1f}, "

@@ -63,11 +63,8 @@ def test_masked_mean_no_correction():
 
 def test_shard_map_psum_path_matches():
     """aggregate_grads_local under shard_map == aggregate_grads globally."""
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map            # jax >= 0.5
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
 
     U, L, F = 4, 3, 6   # single CPU device -> 1 shard holding all clients
     g = _rand((U, L, F), 0)

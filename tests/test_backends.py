@@ -460,6 +460,8 @@ def test_shard_map_multi_device_subprocess():
     env["PYTHONPATH"] = os.path.join(REPO, "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
+    # the child runs on forced host devices and never contends for a chip
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", _MULTIDEV_SCRIPT], env=env,
                           capture_output=True, text=True, timeout=900)
     assert proc.returncode == 0 and "MULTIDEV_OK" in proc.stdout, (

@@ -117,6 +117,11 @@ def test_ssd_scan_state_carry_vs_chunking():
 # ADEL aggregation
 # ---------------------------------------------------------------------------
 
+def _layer_major(x):
+    """(U, L, ...) client-major -> the kernels' (L, U, ...) layout."""
+    return jnp.swapaxes(x, 0, 1)
+
+
 @pytest.mark.parametrize("U,L,F,bf", [
     (4, 3, 512, 512),
     (16, 8, 1024, 256),
@@ -126,7 +131,7 @@ def test_ssd_scan_state_carry_vs_chunking():
 def test_adel_agg_sweep(U, L, F, bf, dtype):
     g = _qs((U, L, F), 0, dtype)
     c = jax.random.uniform(jax.random.PRNGKey(1), (U, L)).astype(dtype)
-    out = adel_agg(g, c, block_f=bf, interpret=True)
+    out = adel_agg(_layer_major(g), c.T, block_f=bf, interpret=True)
     ref = adel_agg_ref(g, c)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **TOL[dtype])
@@ -141,7 +146,7 @@ def test_adel_agg_nonmultiple_feature_dim(U, L, F, bf):
     """The kernel pads the flattened feature dim and slices the output."""
     g = _qs((U, L, F), 0)
     c = jax.random.uniform(jax.random.PRNGKey(1), (U, L))
-    out = adel_agg(g, c, block_f=bf, interpret=True)
+    out = adel_agg(_layer_major(g), c.T, block_f=bf, interpret=True)
     assert out.shape == (L, F)
     ref = adel_agg_ref(g, c)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -172,8 +177,8 @@ def test_adel_agg_q8_sweep(U, L, F, bf, dtype):
     acceptance tolerance is atol 1e-2 in interpret mode)."""
     q, scale = _quantize(_qs((U, L, F), 0))
     c = jax.random.uniform(jax.random.PRNGKey(1), (U, L))
-    out = adel_agg_q8(q, scale.astype(dtype), c.astype(dtype),
-                      block_f=bf, interpret=True)
+    out = adel_agg_q8(_layer_major(q), scale.T.astype(dtype),
+                      c.T.astype(dtype), block_f=bf, interpret=True)
     assert out.shape == (L, F) and out.dtype == jnp.float32
     ref = adel_agg_q8_ref(q, scale.astype(dtype), c.astype(dtype))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref, np.float32),
@@ -187,7 +192,8 @@ def test_adel_agg_q8_zero_coefficient_rows():
     q, scale = _quantize(_qs((U, L, F), 2))
     c = jax.random.uniform(jax.random.PRNGKey(3), (U, L))
     c = c.at[1].set(0.0).at[4].set(0.0)
-    out = adel_agg_q8(q, scale, c, block_f=64, interpret=True)
+    out = adel_agg_q8(_layer_major(q), scale.T, c.T, block_f=64,
+                      interpret=True)
     keep = jnp.asarray([0, 2, 3, 5])
     ref = adel_agg_q8_ref(q[keep], scale[keep], c[keep])
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -201,7 +207,8 @@ def test_adel_agg_q8_zero_scale_layer():
     g = _qs((U, L, F), 4).at[:, 1, :].set(0.0)
     q, scale = _quantize(g)
     c = jnp.ones((U, L))
-    out = adel_agg_q8(q, scale, c, block_f=64, interpret=True)
+    out = adel_agg_q8(_layer_major(q), scale.T, c.T, block_f=64,
+                      interpret=True)
     assert np.all(np.isfinite(np.asarray(out)))
     np.testing.assert_array_equal(np.asarray(out[1]), 0.0)
 
@@ -213,7 +220,8 @@ def test_adel_agg_q8_dequant_error_bound():
     g = _qs((U, L, F), 5)
     q, scale = _quantize(g)
     c = jax.random.uniform(jax.random.PRNGKey(6), (U, L))
-    out = adel_agg_q8(q, scale, c, block_f=128, interpret=True)
+    out = adel_agg_q8(_layer_major(q), scale.T, c.T, block_f=128,
+                      interpret=True)
     dense = adel_agg_ref(g, c)
     bound = jnp.sum(c * jnp.max(jnp.abs(g), axis=-1) / 254.0, axis=0)
     err = jnp.max(jnp.abs(out - dense), axis=-1)

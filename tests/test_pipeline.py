@@ -159,6 +159,22 @@ def test_exec_spec_pipeline_validation_and_cli():
     args = ap.parse_args(["--pipeline", "prefetch"])
     assert ExecSpec.from_cli(args).pipeline == "prefetch"
     assert ExecSpec.from_cli(ap.parse_args([])).pipeline == "serial"
-    # --compile-cache is a process-level jax flag, not a spec field
-    args = ap.parse_args(["--compile-cache", ""])
-    assert not hasattr(ExecSpec.from_cli(args), "compile_cache")
+
+
+def test_compile_cache_fixed_path(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the helper leaves JAX's config
+    alone; unset, the cache goes to the checkout's fixed .jax_cache."""
+    from pathlib import Path
+
+    from repro.launch.compile_cache import CACHE_DIR, use_compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert use_compile_cache() == str(CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(CACHE_DIR)
+        assert CACHE_DIR == Path(__file__).resolve().parents[1] / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

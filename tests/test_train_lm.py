@@ -219,3 +219,18 @@ def test_run_training_api_and_checkpoint(tmp_path):
     assert os.path.exists(ckpt + ".npz") and os.path.exists(ckpt + ".json")
     # static population: drift never fires, but the hook path ran
     assert hist.replans == []
+
+
+def test_profile_dir_failure_fails_the_run(tmp_path, monkeypatch):
+    """A run asked for a device trace never exits 0 without one: here the
+    profiler cannot start because another trace is already running."""
+    from repro.launch import train
+    # set, so the entry point leaves this process's JAX cache config alone
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    jax.profiler.start_trace(str(tmp_path / "other"))
+    try:
+        with pytest.raises(RuntimeError):
+            train.main(["--arch", ARCH, "--rounds", "1",
+                        "--profile-dir", str(tmp_path / "trace")])
+    finally:
+        jax.profiler.stop_trace()
