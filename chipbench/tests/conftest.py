@@ -1,0 +1,11 @@
+"""The self-checks run on the CPU, with a compile cache of their own."""
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = Path(__file__).resolve().parents[2]
+sys.path.insert(0, str(ROOT))
+sys.path.insert(0, str(ROOT / "src"))
+DATA = Path(__file__).resolve().parent / "data"
+CELL = (DATA / "BENCHMARK.json", DATA)
