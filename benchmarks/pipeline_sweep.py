@@ -55,6 +55,8 @@ def _row(hist, wall: float, rounds: int, mode: str) -> dict:
         row["dispatch_wait_s"] = round(
             float(c.get("dispatch_wait_s", 0.0)), 4)
         row["overlap_frac"] = round(row["overlap_s"] / max(wall, 1e-9), 4)
+        # bytes copied from host memory: the fleet's host-stacked cohorts;
+        # the LM's cohort and plans already live on the device
         row["h2d_bytes"] = int(c.get("h2d_bytes", 0))
     return row
 

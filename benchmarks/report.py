@@ -254,11 +254,11 @@ def section_telemetry() -> str:
                 continue
             drift = tel["drift"]
             phases = tel.get("phases", {})
+            step = ("round_step", "local_train", "aggregate")
             train_s = sum(phases.get(p, {}).get("total_s", 0.0)
-                          for p in ("local_train", "aggregate"))
+                          for p in step)
             other_s = sum(v.get("total_s", 0.0)
-                          for k, v in phases.items()
-                          if k not in ("local_train", "aggregate"))
+                          for k, v in phases.items() if k not in step)
             ctr = tel.get("counters", {})
             logical = ctr.get("aggregate_bytes_logical", 0)
             wire = ctr.get("aggregate_bytes_wire", 0)

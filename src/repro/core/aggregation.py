@@ -128,13 +128,18 @@ def aggregate_grads_local(local_grads: PyTree, layer_ids: PyTree,
     client axis; counts and weighted sums are combined with jax.lax.psum.
 
     local_grads leaves: (U_local,) + param.shape; local_mask: (U_local, L).
+    The psums run under the ``exchange`` named scope, the weighting under
+    ``fold``.
     """
-    counts = jax.lax.psum(local_mask.sum(0), axis_name)       # (L,) global
-    c = layer_coefficients(local_mask, p, bias_correct=bias_correct,
-                           counts=counts)
-    partial = jax.tree.map(lambda g, ids: _weight_leaf(g, ids, c),
-                           local_grads, layer_ids)
-    return jax.lax.psum(partial, axis_name)
+    with jax.named_scope("exchange"):
+        counts = jax.lax.psum(local_mask.sum(0), axis_name)   # (L,) global
+    with jax.named_scope("fold"):
+        c = layer_coefficients(local_mask, p, bias_correct=bias_correct,
+                               counts=counts)
+        partial = jax.tree.map(lambda g, ids: _weight_leaf(g, ids, c),
+                               local_grads, layer_ids)
+    with jax.named_scope("exchange"):
+        return jax.lax.psum(partial, axis_name)
 
 
 def aggregate_grads_chunk(chunk_grads: PyTree, layer_ids: PyTree,

@@ -8,6 +8,9 @@ A policy decides, per round t:
     width-overlap mean).
 
 All randomness flows through explicit PRNG keys so runs are reproducible.
+Each policy's straggler draw runs under a ``repro.draw`` profiler
+annotation (:func:`repro.obs.annotate`), inside the runtime's
+``repro.plan``.
 """
 from __future__ import annotations
 
@@ -17,6 +20,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro import obs
 
 from . import straggler
 from .types import AnalysisConfig, Schedule
@@ -81,7 +86,9 @@ class AdelPolicy(Policy):
     def round(self, key, t, view=None):
         cfg = self._resolve(view)
         T_t = float(self.schedule.T[t])
-        mask, p, S, _ = straggler.sample_round(key, T_t, self.schedule.m, cfg)
+        with obs.annotate("draw"):
+            mask, p, S, _ = straggler.sample_round(key, T_t, self.schedule.m,
+                                                   cfg)
         return RoundPlan(mask=mask, p=p, batch_sizes=S, elapsed=T_t,
                          bias_correct=True)
 
@@ -106,7 +113,9 @@ class SalfPolicy(Policy):
     def round(self, key, t, view=None):
         cfg = self._resolve(view)
         S_fix = self._fixed_batch(view, self.T_t)
-        mask, p, _ = straggler.sample_round_fixed(key, self.T_t, S_fix, cfg)
+        with obs.annotate("draw"):
+            mask, p, _ = straggler.sample_round_fixed(key, self.T_t, S_fix,
+                                                      cfg)
         S = jnp.full((cfg.U,), S_fix)
         return RoundPlan(mask=mask, p=p, batch_sizes=S, elapsed=self.T_t,
                          bias_correct=True)
@@ -131,9 +140,10 @@ class DropPolicy(Policy):
     def round(self, key, t, view=None):
         cfg = self._resolve(view)
         S_fix = self._fixed_batch(view, self.T_t)
-        P, B = jnp.asarray(cfg.P), jnp.asarray(cfg.B_eff)
-        lam = P / S_fix * jnp.maximum(self.T_t - B, 0.0)
-        z = straggler.sample_depths(key, lam)
+        with obs.annotate("draw"):
+            P, B = jnp.asarray(cfg.P), jnp.asarray(cfg.B_eff)
+            lam = P / S_fix * jnp.maximum(self.T_t - B, 0.0)
+            z = straggler.sample_depths(key, lam)
         full = (z >= cfg.L).astype(jnp.float32)                  # (U,)
         mask = jnp.broadcast_to(full[:, None], (cfg.U, cfg.L))
         S = jnp.full((cfg.U,), S_fix)
@@ -157,11 +167,13 @@ class WaitPolicy(Policy):
     def round(self, key, t, view=None):
         cfg = self._resolve(view)
         S_fix = self._fixed_batch(view, self.T_ref)
-        P, B = jnp.asarray(cfg.P), jnp.asarray(cfg.B_eff)
-        # full backprop time = sum of L iid Exp(S/P) = Gamma(L, scale=S/P);
-        # with a FIXED batch the slowest device dominates the round clock
-        g = jax.random.gamma(key, cfg.L, shape=(cfg.U,)) * (S_fix / P)
-        elapsed = float(jnp.max(g + B))
+        with obs.annotate("draw"):
+            P, B = jnp.asarray(cfg.P), jnp.asarray(cfg.B_eff)
+            # full backprop time = sum of L iid Exp(S/P) = Gamma(L,
+            # scale=S/P); with a FIXED batch the slowest device dominates
+            # the round clock
+            g = jax.random.gamma(key, cfg.L, shape=(cfg.U,)) * (S_fix / P)
+            elapsed = float(jnp.max(g + B))
         mask = jnp.ones((cfg.U, cfg.L), jnp.float32)
         S = jnp.full((cfg.U,), S_fix)
         return RoundPlan(mask=mask, p=jnp.zeros(cfg.L), batch_sizes=S,
@@ -201,12 +213,14 @@ class HeteroFLPolicy(Policy):
         cfg = self._resolve(view)
         ratios = (self.ratios if view is None
                   else self._capability_ratios(cfg.P))
-        P, B = jnp.asarray(cfg.P), jnp.asarray(cfg.B_eff)
-        S_fix = straggler.fixed_batch(self.T_t, self.m, cfg)
-        r = jnp.asarray(ratios)
-        # per-layer time Exp(S r^2 / P) -> completed layers ~ Poisson(P (T-B) / (S r^2))
-        lam = P / (S_fix * r ** 2) * jnp.maximum(self.T_t - B, 0.0)
-        z = straggler.sample_depths(key, lam)
+        with obs.annotate("draw"):
+            P, B = jnp.asarray(cfg.P), jnp.asarray(cfg.B_eff)
+            S_fix = straggler.fixed_batch(self.T_t, self.m, cfg)
+            r = jnp.asarray(ratios)
+            # per-layer time Exp(S r^2 / P) -> completed layers
+            # ~ Poisson(P (T-B) / (S r^2))
+            lam = P / (S_fix * r ** 2) * jnp.maximum(self.T_t - B, 0.0)
+            z = straggler.sample_depths(key, lam)
         full = (z >= cfg.L).astype(jnp.float32)
         mask = jnp.broadcast_to(full[:, None], (cfg.U, cfg.L))
         S = jnp.full((cfg.U,), S_fix)

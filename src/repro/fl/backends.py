@@ -74,13 +74,24 @@ an Eq. 5 coefficient fold — and reject compression with a ``ValueError``.
 
 Telemetry: every backend carries the runtime's tracer (``set_tracer``,
 default :data:`repro.obs.NULL_TRACER`). The fused single-dispatch backends
-(dense / shard_map / temporal) emit one ``local_train`` span per round plus
+(dense / shard_map / temporal, and buffered's train + on-time fold) emit
+one ``round_step`` span (``fused=True``) per round plus
 ``aggregate_bytes_logical`` / ``aggregate_bytes_wire`` counters (dense
 float32 pytree size vs post-compression payload size, both analytic and
 exactly deterministic); the chunked backend emits one ``local_train`` span
 and one counter pair per chunk and a separate ``aggregate`` span around the
-final apply. Active tracers block on step results so spans measure device
-work rather than async dispatch — numerics are untouched either way.
+final apply. Every span is also a ``repro.<phase>`` profiler annotation,
+tracer or not. Tracers with ``sync=True`` block on step results so spans
+measure device work rather than async dispatch — numerics are untouched
+either way.
+
+Inside the fused round steps, ``jax.named_scope`` s mark what each device
+op belongs to: ``local_train`` (each client's local update, forward and
+backward; backward ops carry ``transpose(jvp(...))`` below it), ``fold``
+(the Eq. 5 coefficient weighting and the accumulator add, wire
+quantization included), ``exchange`` (the shard_map step's psums) and
+``server_apply`` (``w - agg``). Scopes are op metadata only: the compiled
+program is the same without them.
 """
 from __future__ import annotations
 
@@ -158,8 +169,8 @@ class ExecutionBackend:
 
     def set_tracer(self, tracer) -> None:
         """Attach the runtime's tracer (:class:`repro.obs.Tracer`) so the
-        backend's ``local_train`` / ``aggregate`` spans and bytes counters
-        land in the same event stream."""
+        backend's ``round_step`` / ``local_train`` / ``aggregate`` spans and
+        bytes counters land in the same event stream."""
         self.tracer = tracer if tracer is not None else obs.NULL_TRACER
 
     def _round_bytes(self, params_like: PyTree, U: int) -> tuple[int, int]:
@@ -190,18 +201,19 @@ class ExecutionBackend:
                 f"with HeteroFL width-mask aggregation")
 
     def _traced_fused(self, step, params, *args):
-        """Run a fused train+aggregate jit step under a ``local_train``
+        """Run a fused train+aggregate jit step under a ``round_step``
         span (the single-dispatch backends cannot split aggregation out of
-        the compiled step). An active tracer blocks on the result so the
-        span measures device work, not async dispatch; trajectories are
-        unchanged."""
+        the compiled step on the host; the step's named scopes split it on
+        the device). A tracer with ``sync=True`` blocks on the result so
+        the span measures device work, not async dispatch; trajectories
+        are unchanged."""
         tracer = self.tracer
-        if not tracer.active:
-            return step(params, *args)
-        with tracer.span("local_train", backend=self.name, fused=True):
+        with tracer.span("round_step", backend=self.name, fused=True):
             out = step(params, *args)
-            jax.block_until_ready(out)
-        self._count_bytes(out, int(args[3].shape[0]))   # args[3] = mask
+            if tracer.sync:
+                jax.block_until_ready(out)
+        if tracer.active:
+            self._count_bytes(out, int(args[3].shape[0]))   # args[3] = mask
         return out
 
     @property
@@ -236,7 +248,8 @@ class ExecutionBackend:
         census). Host-side branch decisions (buffered's bank-or-not,
         hierarchical's region split) read the real ``mask``/``ctx``
         values, so the variant round 0 will run is the variant that gets
-        compiled. Telemetry is suppressed for the dummy round.
+        compiled. Telemetry is suppressed for the dummy round (its
+        profiler annotations remain, inside the runtime's ``warm_up``).
         """
         t0 = obs.now()
         dummy = jax.tree.map(lambda a: jnp.zeros(jnp.shape(a),
@@ -265,9 +278,10 @@ class ExecutionBackend:
 
     # shared sub-computations -------------------------------------------
     def _deltas(self, params, xb, yb, wb, eta):
-        return batched_client_deltas(self.model.loss, params, xb, yb, wb,
-                                     eta, local_iters=self.local_iters,
-                                     l2=self.l2)
+        with jax.named_scope("local_train"):
+            return batched_client_deltas(self.model.loss, params, xb, yb, wb,
+                                         eta, local_iters=self.local_iters,
+                                         l2=self.l2)
 
 
 class DenseBackend(ExecutionBackend):
@@ -290,26 +304,30 @@ class DenseBackend(ExecutionBackend):
             def step(params, xb, yb, wb, mask, p, eta, wmasks):
                 deltas = self._deltas(params, xb, yb, wb, eta)
                 ids = self.model.layer_ids(params)
-                if hetero:
-                    num, den = hetero_overlap_partials(deltas, wmasks,
-                                                       mask[:, 0])
-                    agg = hetero_overlap_mean(num, den)
-                elif comp.mode != "none":
-                    # the reduction consumes the int8 wire payload: the
-                    # float32 delta tree never feeds the aggregation
-                    payload = compress_deltas(deltas, ids, comp)
-                    agg = aggregate_compressed(
-                        payload, params, ids, mask, p, cfg=comp,
-                        bias_correct=bias_correct, agg_impl=self.agg_impl)
-                    return jax.tree.map(_sub32, params, agg)
-                elif self.agg_impl == "pallas":
-                    from repro.kernels.ops import adel_aggregate_pallas
-                    agg = adel_aggregate_pallas(deltas, ids, mask, p,
-                                                bias_correct=bias_correct)
-                else:
-                    agg = aggregate_grads(deltas, ids, mask, p,
-                                          bias_correct=bias_correct)
-                return jax.tree.map(lambda w, d: w - d, params, agg)
+                with jax.named_scope("fold"):
+                    if hetero:
+                        num, den = hetero_overlap_partials(deltas, wmasks,
+                                                           mask[:, 0])
+                        agg = hetero_overlap_mean(num, den)
+                    elif comp.mode != "none":
+                        # the reduction consumes the int8 wire payload: the
+                        # float32 delta tree never feeds the aggregation
+                        payload = compress_deltas(deltas, ids, comp)
+                        agg = aggregate_compressed(
+                            payload, params, ids, mask, p, cfg=comp,
+                            bias_correct=bias_correct,
+                            agg_impl=self.agg_impl)
+                    elif self.agg_impl == "pallas":
+                        from repro.kernels.ops import adel_aggregate_pallas
+                        agg = adel_aggregate_pallas(deltas, ids, mask, p,
+                                                    bias_correct=bias_correct)
+                    else:
+                        agg = aggregate_grads(deltas, ids, mask, p,
+                                              bias_correct=bias_correct)
+                with jax.named_scope("server_apply"):
+                    sub = (_sub32 if comp.mode != "none"
+                           else lambda w, d: w - d)
+                    return jax.tree.map(sub, params, agg)
 
             self._steps[key] = jax.jit(step,
                                        donate_argnums=self._donate_params)
@@ -432,14 +450,14 @@ class ChunkedBackend(ExecutionBackend):
             with tracer.span("local_train", backend=self.name,
                              chunk=c0 // c):
                 payload = payload_step(params, xb[sl], yb[sl], wb[sl], eta)
-                if tracer.active:
+                if tracer.sync:
                     jax.block_until_ready(payload)
             if tracer.active:
                 self._count_bytes(params, c)
             acc = fold(acc, params, payload, mask[sl], p, counts)
         with tracer.span("aggregate", backend=self.name, chunks=-(-U // c)):
             out = self._apply32(params, acc)
-            if tracer.active:
+            if tracer.sync:
                 jax.block_until_ready(out)
         return out
 
@@ -469,7 +487,7 @@ class ChunkedBackend(ExecutionBackend):
                              chunk=c0 // c):
                 part = step(params, xb[sl], yb[sl], wb[sl], mask[sl], p, eta,
                             counts, wm_c)
-                if tracer.active:
+                if tracer.sync:
                     jax.block_until_ready(part)
             if tracer.active:
                 self._count_bytes(params, c)
@@ -483,7 +501,7 @@ class ChunkedBackend(ExecutionBackend):
                          chunks=-(-U // c)):
             out = (self._apply_hetero(params, num, den) if hetero
                    else self._apply(params, agg))
-            if tracer.active:
+            if tracer.sync:
                 jax.block_until_ready(out)
         return out
 
@@ -543,28 +561,37 @@ class ShardMapBackend(ExecutionBackend):
                 deltas = self._deltas(params, xb, yb, wb, eta)
                 ids = model.layer_ids(params)
                 if hetero:
-                    num, den = hetero_overlap_partials(deltas, wmasks_l,
-                                                       mask_l[:, 0])
-                    num = jax.lax.psum(num, ax)
-                    den = jax.lax.psum(den, ax)
-                    agg = hetero_overlap_mean(num, den)
+                    with jax.named_scope("fold"):
+                        num, den = hetero_overlap_partials(deltas, wmasks_l,
+                                                           mask_l[:, 0])
+                    with jax.named_scope("exchange"):
+                        num = jax.lax.psum(num, ax)
+                        den = jax.lax.psum(den, ax)
+                    with jax.named_scope("fold"):
+                        agg = hetero_overlap_mean(num, den)
                 elif comp.mode != "none":
                     # each shard's reduction consumes its clients' int8
                     # payload; the psum combines float32 shard partials
                     # (the jnp fold — Pallas inside shard_map is not
                     # supported in interpret mode)
-                    counts = jax.lax.psum(mask_l.sum(0), ax)
-                    payload = compress_deltas(deltas, ids, comp)
-                    part = aggregate_compressed(
-                        payload, params, ids, mask_l, p, cfg=comp,
-                        counts=counts, bias_correct=bias_correct,
-                        agg_impl="jnp")
-                    agg = jax.lax.psum(part, ax)
-                    return jax.tree.map(_sub32, params, agg)
+                    with jax.named_scope("exchange"):
+                        counts = jax.lax.psum(mask_l.sum(0), ax)
+                    with jax.named_scope("fold"):
+                        payload = compress_deltas(deltas, ids, comp)
+                        part = aggregate_compressed(
+                            payload, params, ids, mask_l, p, cfg=comp,
+                            counts=counts, bias_correct=bias_correct,
+                            agg_impl="jnp")
+                    with jax.named_scope("exchange"):
+                        agg = jax.lax.psum(part, ax)
                 else:
+                    # scoped inside: fold, with its psums as exchange
                     agg = aggregate_grads_local(deltas, ids, mask_l, p, ax,
                                                 bias_correct=bias_correct)
-                return jax.tree.map(lambda w, d: w - d, params, agg)
+                with jax.named_scope("server_apply"):
+                    sub = (_sub32 if comp.mode != "none" and not hetero
+                           else lambda w, d: w - d)
+                    return jax.tree.map(sub, params, agg)
 
             spec_c = P(ax)      # leading client axis sharded over batch axes
             spec_r = P()        # replicated
@@ -619,33 +646,39 @@ class TemporalBackend(ExecutionBackend):
             model = self.model
 
             def delta_u(params, x_u, y_u, w_u, eta):
-                return local_update(model.loss, params, x_u, y_u, w_u, eta,
-                                    local_iters=self.local_iters, l2=self.l2)
+                with jax.named_scope("local_train"):
+                    return local_update(model.loss, params, x_u, y_u, w_u,
+                                        eta, local_iters=self.local_iters,
+                                        l2=self.l2)
 
             def step(params, xb, yb, wb, mask, p, eta, wmasks):
                 ids = model.layer_ids(params)
-                zeros32 = jax.tree.map(
-                    lambda w: jnp.zeros(w.shape, jnp.float32), params)
+                with jax.named_scope("fold"):
+                    zeros32 = jax.tree.map(
+                        lambda w: jnp.zeros(w.shape, jnp.float32), params)
                 if hetero:
                     part = mask[:, 0]                       # (U,)
 
                     def body(acc, inp):
                         x_u, y_u, w_u, pt_u, wm_u = inp
                         d = delta_u(params, x_u, y_u, w_u, eta)
-                        num, den = acc
-                        num = jax.tree.map(
-                            lambda n, dd, wm: n + pt_u * wm
-                            * dd.astype(jnp.float32), num, d, wm_u)
-                        den = jax.tree.map(
-                            lambda dn, wm: dn + pt_u * wm, den, wm_u)
+                        with jax.named_scope("fold"):
+                            num, den = acc
+                            num = jax.tree.map(
+                                lambda n, dd, wm: n + pt_u * wm
+                                * dd.astype(jnp.float32), num, d, wm_u)
+                            den = jax.tree.map(
+                                lambda dn, wm: dn + pt_u * wm, den, wm_u)
                         return (num, den), None
 
                     (num, den), _ = jax.lax.scan(
                         body, (zeros32, zeros32), (xb, yb, wb, part, wmasks))
-                    agg = hetero_overlap_mean(num, den)
+                    with jax.named_scope("fold"):
+                        agg = hetero_overlap_mean(num, den)
                 else:
-                    coeffs = layer_coefficients(mask, p,
-                                                bias_correct=bias_correct)
+                    with jax.named_scope("fold"):
+                        coeffs = layer_coefficients(mask, p,
+                                                    bias_correct=bias_correct)
                     comp = self.compression
 
                     if comp.mode != "none":
@@ -653,9 +686,7 @@ class TemporalBackend(ExecutionBackend):
                         # its wire form, then dequant+weight+accumulate
                         # against this client's GLOBAL-count coefficient
                         # row — peak memory stays one delta pytree
-                        def body(acc, inp):
-                            x_u, y_u, w_u, c_row = inp
-                            d = delta_u(params, x_u, y_u, w_u, eta)
+                        def fold_u(acc, d, c_row):
                             d1 = jax.tree.map(
                                 lambda dd: dd.astype(jnp.float32)[None], d)
                             payload = compress_deltas(d1, ids, comp)
@@ -663,33 +694,36 @@ class TemporalBackend(ExecutionBackend):
                                 payload, params, ids, None, None, cfg=comp,
                                 coeffs=c_row[None],
                                 agg_impl=self.agg_impl)
-                            return jax.tree.map(jnp.add, acc, dw), None
+                            return jax.tree.map(jnp.add, acc, dw)
                     elif self.agg_impl == "pallas":
                         from repro.kernels.ops import adel_aggregate_pallas
 
-                        def body(acc, inp):
-                            x_u, y_u, w_u, c_row = inp
-                            d = delta_u(params, x_u, y_u, w_u, eta)
+                        def fold_u(acc, d, c_row):
                             d1 = jax.tree.map(
                                 lambda dd: dd.astype(jnp.float32)[None], d)
                             dw = adel_aggregate_pallas(d1, ids, None, None,
                                                        coeffs=c_row[None])
-                            return jax.tree.map(jnp.add, acc, dw), None
+                            return jax.tree.map(jnp.add, acc, dw)
                     else:
-                        def body(acc, inp):
-                            x_u, y_u, w_u, c_row = inp
-                            d = delta_u(params, x_u, y_u, w_u, eta)
+                        def fold_u(acc, d, c_row):
                             dw = jax.tree.map(
                                 lambda dd, idl: weight_by_layer(
                                     dd.astype(jnp.float32), idl, c_row),
                                 d, ids)
-                            return jax.tree.map(jnp.add, acc, dw), None
+                            return jax.tree.map(jnp.add, acc, dw)
+
+                    def body(acc, inp):
+                        x_u, y_u, w_u, c_row = inp
+                        d = delta_u(params, x_u, y_u, w_u, eta)
+                        with jax.named_scope("fold"):
+                            return fold_u(acc, d, c_row), None
 
                     agg, _ = jax.lax.scan(body, zeros32,
                                           (xb, yb, wb, coeffs))
-                return jax.tree.map(
-                    lambda w, d: (w.astype(jnp.float32)
-                                  - d).astype(w.dtype), params, agg)
+                with jax.named_scope("server_apply"):
+                    return jax.tree.map(
+                        lambda w, d: (w.astype(jnp.float32)
+                                      - d).astype(w.dtype), params, agg)
 
             self._steps[key] = jax.jit(step,
                                        donate_argnums=self._donate_params)
@@ -900,9 +934,9 @@ class BufferedBackend(DenseBackend):
         # 3. the fused train + on-time aggregate (+ bankable payload)
         tracer = self.tracer
         step = self._main(bool(bias_correct), bank)
-        with tracer.span("local_train", backend=self.name, fused=True):
+        with tracer.span("round_step", backend=self.name, fused=True):
             out = step(params, xb, yb, wb, mask, p, eta)
-            if tracer.active:
+            if tracer.sync:
                 jax.block_until_ready(out)
         params, banked = out if bank else (out, None)
         if tracer.active:
@@ -917,7 +951,7 @@ class BufferedBackend(DenseBackend):
                 for slot, w in folds:
                     params = fold(params, slot["banked"], slot["c_late"],
                                   jnp.asarray(w))
-                if tracer.active:
+                if tracer.sync:
                     jax.block_until_ready(params)
 
         # 5. bank this round's late work (ring eviction drops the oldest)
@@ -1061,7 +1095,7 @@ class HierarchicalBackend(ChunkedBackend):
                                  region=j):
                     payload = payload_step(params, xb[idx], yb[idx],
                                            wb[idx], eta)
-                    if tracer.active:
+                    if tracer.sync:
                         jax.block_until_ready(payload)
                 if tracer.active:
                     self._count_bytes(params, len(groups[j]))
@@ -1069,7 +1103,7 @@ class HierarchicalBackend(ChunkedBackend):
             with tracer.span("aggregate", backend=self.name,
                              regions=len(groups)):
                 out = self._apply32(params, acc)
-                if tracer.active:
+                if tracer.sync:
                     jax.block_until_ready(out)
             return out
 
@@ -1082,7 +1116,7 @@ class HierarchicalBackend(ChunkedBackend):
             with tracer.span("local_train", backend=self.name, region=j):
                 part = step(params, xb[idx], yb[idx], wb[idx], m_r, p, eta,
                             counts, wm_r)
-                if tracer.active:
+                if tracer.sync:
                     jax.block_until_ready(part)
             if tracer.active:
                 self._count_bytes(params, len(groups[j]))
@@ -1097,7 +1131,7 @@ class HierarchicalBackend(ChunkedBackend):
                          regions=len(groups)):
             out = (self._apply_hetero(params, num, den) if hetero
                    else self._apply(params, agg))
-            if tracer.active:
+            if tracer.sync:
                 jax.block_until_ready(out)
         return out
 

@@ -50,14 +50,21 @@ Observability flows through the single ``tracer=`` hook
 (:mod:`repro.obs`, default :data:`repro.obs.NULL_TRACER` — zero overhead,
 bit-identical trajectories): the runtime emits nestable phase spans
 (``cohort`` / ``replan`` / ``plan`` / ``stack`` / ``eval`` /
-``checkpoint``; the execution backends add ``local_train`` /
-``aggregate``), typed counters (padded-vs-real batch elements, skipped
-rounds, replan solver steps), and one clock-model ledger event per
-executed round (:func:`repro.obs.ledger.round_record`: planned deadline
-vs simulated clock vs measured wall time vs the exponential model's
-predicted straggler depths). ``verbose=True`` renders from the same
-records via :mod:`repro.obs.format`, so printed and recorded numbers
+``checkpoint``; the execution backends add ``round_step``, or
+``local_train`` / ``aggregate``), typed counters (padded-vs-real batch
+elements, bytes copied from host memory), and one clock-model ledger
+event per executed round (:func:`repro.obs.ledger.round_record`: planned
+deadline vs simulated clock vs measured wall time vs the exponential
+model's predicted straggler depths). ``verbose=True`` renders from the
+same records via :mod:`repro.obs.format`, so printed and recorded numbers
 cannot drift apart; the aggregate lands in ``History.telemetry``.
+
+Every phase is also a ``repro.<phase>`` profiler annotation, tracer or
+not (:func:`repro.obs.annotate`), nested as ``repro.run`` ⊃
+``repro.round`` (``round=t``) ⊃ the phases, with the policies'
+``repro.draw`` inside ``repro.plan``; the prefetch worker's phases sit
+under its own ``repro.prefetch`` root. A profiler trace thus places the
+program's own spans on the device trace's clock.
 
 Pipelined execution (``ExecSpec.pipeline``) — the round loop runs in one
 of two modes, with bit-identical trajectories:
@@ -67,15 +74,16 @@ of two modes, with bit-identical trajectories:
 * ``"prefetch"``: a one-round-lookahead driver. Every host-only phase of
   round t+1 — ``cohort`` sampling, the replan trigger + re-solve, the
   PRNG key splits, the policy ``plan``, the ``T_max`` stop check, and the
-  minibatch ``stack`` (host numpy + H2D transfer) — runs on a worker
+  minibatch ``stack`` (the draw and any H2D transfer) — runs on a worker
   thread while round t's ``backend.run_round`` is in flight on the
   device. Those phases read only sequential host state (source RNG,
   schedule, replanner, the PLANNED clock — ``plan.elapsed`` is known
   before execution), never round t's device results, which is what makes
   the speculation exact. The two things that do read live state stay on
   the main thread at consume time: HeteroFL width masks (need current
-  ``params``) and all telemetry emission (the worker only collects
-  timings; see :meth:`repro.obs.Tracer.span_record`). The prefetcher
+  ``params``) and all telemetry emission (the worker only opens its
+  profiler annotations and collects timings; see
+  :meth:`repro.obs.Tracer.span_record`). The prefetcher
   keeps at most two rounds of stacked ``(xb, yb, wb, mask)`` buffers
   alive (the in-flight round's and the prefetched round's — a double
   buffer whose slots are dropped right after dispatch), and it never
@@ -89,16 +97,19 @@ of two modes, with bit-identical trajectories:
   modes: ``eval_fn`` returns device scalars that sit in a pending ring
   and are materialized to ``History`` floats only at report boundaries
   (a rendered eval record, a replan event, an ``on_round`` hook, end of
-  run) — the only hard syncs left are the ones an active tracer
-  explicitly inserts. New counters: ``h2d_bytes`` (stacked bytes shipped
-  per round), ``prefetch_overlap_s`` (worker planning time hidden behind
-  device execution), ``dispatch_wait_s`` (main-thread stalls on the
-  prefetch future), ``warm_up_s``.
+  run) — the only hard syncs left are the ones an active tracer with
+  ``sync=True`` explicitly inserts. Counters: ``h2d_bytes`` (bytes the
+  runtime copies from host memory to the device per round: the cohort,
+  plan and learning-rate arrays that are not device arrays already),
+  ``prefetch_overlap_s`` (worker planning time hidden behind device
+  execution), ``dispatch_wait_s`` (main-thread stalls on the prefetch
+  future), ``warm_up_s``.
 """
 from __future__ import annotations
 
 import concurrent.futures
 import dataclasses
+import functools
 from typing import Any, Callable, Optional
 
 import jax
@@ -331,7 +342,7 @@ class _Prepared:
     spans: list                        # [(phase, t0, dur_s, attrs)]
     t_start: float = 0.0               # worker wall window, for the
     t_end: float = 0.0                 # prefetch_overlap_s counter
-    replan: Optional[tuple] = None     # (event record dict, solver steps)
+    replan: Optional[dict] = None      # the replan event's record
     cohort: Any = None
     plan: Any = None
     xb: Any = None
@@ -345,6 +356,15 @@ class _Prepared:
 
     def release(self) -> None:
         self.cohort = self.xb = self.yb = self.wb = self.mask = None
+
+
+def _host_bytes(*arrays) -> int:
+    """Bytes that handing ``arrays`` to the device copies from host memory:
+    those that are not device arrays already, at the dtype JAX gives them
+    there."""
+    return sum(int(np.size(a)) * jax.dtypes.canonicalize_dtype(
+                   np.result_type(a)).itemsize
+               for a in arrays if not isinstance(a, jax.Array))
 
 
 class RoundRuntime:
@@ -412,7 +432,8 @@ class RoundRuntime:
     def _prepare(self, cohort: Cohort, plan: RoundPlan, k_batch, s_max: int,
                  U_pad: int):
         """Draw the per-client minibatches, then pad the cohort axis to the
-        backend's fixed width.
+        backend's fixed width. Also returns the bytes of the inputs that
+        were copied from host memory (the ``h2d_bytes`` counter).
 
         Sampling always happens at the UNPADDED cohort width: jax's
         counter-based PRNG ties the draw to the array shape, so sampling at
@@ -421,18 +442,20 @@ class RoundRuntime:
         their aggregation coefficients are 0, so they contribute nothing.
         """
         U_act = cohort.size
+        inputs = (cohort.x, cohort.y, cohort.counts, plan.batch_sizes)
         xb, yb, wb = sample_client_batches(
-            k_batch, jnp.asarray(cohort.x), jnp.asarray(cohort.y),
-            jnp.asarray(cohort.counts), jnp.asarray(plan.batch_sizes), s_max)
+            k_batch, *(jnp.asarray(a) for a in inputs), s_max)
         mask = jnp.asarray(plan.mask, jnp.float32)
+        h2d = _host_bytes(*inputs, plan.mask)
         if U_pad != U_act:
             pad = U_pad - U_act
             zrow = lambda a: jnp.concatenate(
                 [a, jnp.zeros((pad,) + a.shape[1:], a.dtype)])
             xb, yb, wb, mask = zrow(xb), zrow(yb), zrow(wb), zrow(mask)
-        return xb, yb, wb, mask, U_act
+        return xb, yb, wb, mask, U_act, h2d
 
     # ------------------------------------------------------------------
+    @obs.annotated("run")
     def run(self, source, *, rounds: int, T_max: float, eta, s_max: int,
             key: jax.Array, test_x=None, test_y=None, eval_every: int = 1,
             verbose: bool = False, method: str = "",
@@ -474,7 +497,9 @@ class RoundRuntime:
         stay device scalars in a pending ring and are materialized at
         report boundaries, before every ``on_round`` call, on replan
         events, and at the end of the run — ``History`` always holds plain
-        floats by the time ``run`` returns.
+        floats by the time ``run`` returns. Under a tracer with
+        ``sync=False`` the ledger and eval records, which read device
+        values, are emitted at those same boundaries.
         """
         model, policy, backend = self.model, self.policy, self.backend
         if getattr(policy, "name", "") == "heterofl" and \
@@ -509,8 +534,10 @@ class RoundRuntime:
         # it one round ahead and the trajectory stays bit-identical. The
         # planner's clock mirrors `elapsed` exactly: every planned "round"
         # is later executed, skips spend nothing, and a "stop" halts both.
-        # Telemetry is collected locally and re-emitted at consume time on
-        # the main thread (tracer sinks are not thread-safe).
+        # Each phase opens its profiler annotation live, on the thread that
+        # does the work; the JSONL spans are collected locally and
+        # re-emitted at consume time on the main thread (tracer sinks are
+        # not thread-safe).
         plan_key = key
         planned_elapsed = 0.0
 
@@ -518,7 +545,8 @@ class RoundRuntime:
             nonlocal plan_key, planned_elapsed
             spans: list = []
             t_start = t0 = obs.now()
-            cohort = source.round_cohort(t)
+            with obs.annotate("cohort"):
+                cohort = source.round_cohort(t)
             spans.append(("cohort", t0, obs.now() - t0, {}))
             if cohort is None:
                 # nobody reachable: the round never starts and spends
@@ -539,20 +567,23 @@ class RoundRuntime:
                     if view_fn is not None:
                         view = view_fn(t, budget_left, eta[t:rounds])
                     t0 = obs.now()
-                    ev = replanner.replan(t, budget_left, reachable, view)
-                    spans.append(("replan", t0, obs.now() - t0,
-                                  {"reachable": int(reachable)}))
-                    rep = (ev.as_dict(), int(ev.steps))
-            plan_key, k_round, k_batch = jax.random.split(plan_key, 3)
+                    attrs = {"reachable": int(reachable)}
+                    with obs.annotate("replan", **attrs):
+                        ev = replanner.replan(t, budget_left, reachable, view)
+                    spans.append(("replan", t0, obs.now() - t0, attrs))
+                    rep = ev.as_dict()
             t0 = obs.now()
-            plan: RoundPlan = policy.round(k_round, t, view=cohort.view)
+            with obs.annotate("plan"):
+                plan_key, k_round, k_batch = jax.random.split(plan_key, 3)
+                plan: RoundPlan = policy.round(k_round, t, view=cohort.view)
             spans.append(("plan", t0, obs.now() - t0, {}))
             if planned_elapsed + plan.elapsed > T_max * (1 + 1e-6):
                 return _Prepared(t=t, kind="stop", spans=spans, replan=rep,
                                  t_start=t_start, t_end=obs.now())
             t0 = obs.now()
-            xb, yb, wb, mask, U_act = self._prepare(cohort, plan, k_batch,
-                                                    s_max, U_pad)
+            with obs.annotate("stack"):
+                xb, yb, wb, mask, U_act, h2d = self._prepare(
+                    cohort, plan, k_batch, s_max, U_pad)
             spans.append(("stack", t0, obs.now() - t0, {}))
             view_cfg = (cohort.view if cohort.view is not None
                         else policy.cfg)
@@ -560,169 +591,205 @@ class RoundRuntime:
                                   U_act, regions=cohort.regions)
                    if needs_ctx else None)
             planned_elapsed += plan.elapsed
+            h2d += _host_bytes(plan.p, eta[t])    # handed over at dispatch
             return _Prepared(t=t, kind="round", spans=spans, replan=rep,
                              t_start=t_start, t_end=obs.now(),
                              cohort=cohort, plan=plan, xb=xb, yb=yb, wb=wb,
                              mask=mask, U_act=U_act, view_cfg=view_cfg,
-                             ctx=ctx,
-                             h2d_bytes=obs.tree_bytes((xb, yb, wb, mask)))
+                             ctx=ctx, h2d_bytes=h2d)
+
+        def plan_ahead(t: int) -> _Prepared:
+            # the prefetch worker's root span: its phases are round t's
+            with obs.annotate("prefetch", round=t + 1):
+                return plan_round(t)
+
+        def emit_round(**kw) -> None:
+            """The clock-model ledger row of one round, and the real batch
+            elements it counts (both read the plan's device arrays)."""
+            rec = obs.round_record(L=model.L, U_pad=U_pad, s_max=s_max, **kw)
+            rnd = kw["t"] + 1
+            tracer.count("batch_elements_real", rec["batch_real"],
+                         round=rnd)
+            tracer.event("round", round=rnd, **rec)
 
         pool = (concurrent.futures.ThreadPoolExecutor(
                     max_workers=1, thread_name_prefix="prefetch")
                 if prefetch else None)
         pending: Optional[concurrent.futures.Future] = None
         pending_evals: list[int] = []    # History rows awaiting float()
+        # records that read device values, held back under a tracer with
+        # sync=False until the next drain
+        deferred: list[Callable[[], None]] = []
         warmed = False
         dispatch_t0: Optional[float] = None
 
-        def drain_evals() -> None:
+        def drain() -> None:
             """Materialize deferred eval device scalars into History (the
-            conversion is the sync point; everything before it is free)."""
+            conversion is the sync point; everything before it is free),
+            then emit the records that waited for device values."""
             for i in pending_evals:
                 hist.accuracy[i] = float(hist.accuracy[i])
                 hist.train_loss[i] = float(hist.train_loss[i])
             pending_evals.clear()
+            for emit in deferred:
+                emit()
+            deferred.clear()
+
+        def emit_eval(rec: dict, i: int) -> None:
+            # ONE record for the sink and the console, rendered from exactly
+            # what History keeps
+            rec.update(acc=hist.accuracy[i], loss=hist.train_loss[i])
+            tracer.event("eval", **rec)
+            if verbose:
+                print(obs.format_eval(hist.method, rec))
 
         try:
             for t in range(rounds):
-                tracer.set_round(t + 1)
-                wall_round0 = obs.now() if tracer.active else 0.0
-                if pending is not None:
-                    t0 = obs.now()
-                    prep: _Prepared = pending.result()
-                    pending = None
-                    if tracer.active:
-                        t_res = obs.now()
-                        tracer.count("dispatch_wait_s",
-                                     round(t_res - t0, 6))
-                        tracer.count("prefetch_rounds", 1)
-                        if dispatch_t0 is not None:
-                            # worker wall time hidden behind the device
-                            # dispatch window of the previous round
-                            lo = max(prep.t_start, dispatch_t0)
-                            hi = min(prep.t_end, t_res)
-                            if hi > lo:
-                                tracer.count("prefetch_overlap_s",
-                                             round(hi - lo, 6))
-                else:
-                    prep = plan_round(t)
-                for name, s0, dur, attrs in prep.spans:
-                    tracer.span_record(name, s0, dur, **attrs)
-                if prep.replan is not None:
-                    rec, steps = prep.replan
-                    hist.replans.append(rec)
-                    tracer.event("replan", **rec)
-                    tracer.count("replan_solver_steps", steps)
-                    drain_evals()      # replan events report live state
-                    if verbose:
-                        print(obs.format_replan(hist.method, rec))
-                if prep.kind == "skip":
-                    tracer.count("rounds_skipped", 1)
-                    continue
-                if prep.kind == "stop":
-                    break
-                plan, U_act, view_cfg = prep.plan, prep.U_act, prep.view_cfg
-                wmasks = None
-                if plan.width_ratios is not None:
-                    # HeteroFL masks read the LIVE params tree — the one
-                    # stack input the planner cannot speculate on
-                    t0 = obs.now()
-                    wmasks = self._width_masks(params, plan.width_ratios,
-                                               U_pad)
-                    tracer.span_record("stack", t0, obs.now() - t0,
-                                       part="wmasks")
-                if prefetch and prep.replan is None and t + 1 < rounds:
-                    # overlap round t+1's host phases with round t's device
-                    # step; a replan round forces the next plan inline (and
-                    # a skip `continue`s before this point)
-                    pending = pool.submit(plan_round, t + 1)
-                if prefetch and not warmed:
-                    # AOT warm-up: compile+execute the round step and the
-                    # eval step on dummies before round 0 dispatches, so
-                    # trace cost never lands inside a measured round
-                    t0 = obs.now()
-                    backend.warm_up(params, prep.xb, prep.yb, prep.wb,
-                                    prep.mask, plan.p, jnp.float32(eta[t]),
-                                    bias_correct=bool(plan.bias_correct),
-                                    wmasks=wmasks, ctx=prep.ctx)
-                    dummy = jax.tree.map(
-                        lambda a: jnp.zeros(jnp.shape(a),
-                                            jnp.result_type(a)), params)
-                    jax.block_until_ready(eval_fn(dummy))
-                    dur = obs.now() - t0
-                    tracer.span_record("warm_up", t0, dur)
-                    tracer.count("warm_up_s", round(dur, 6))
-                    warmed = True
-                available = prep.cohort.available
-                dispatch_t0 = obs.now()
-                params = backend.run_round(
-                    params, prep.xb, prep.yb, prep.wb, prep.mask, plan.p,
-                    jnp.float32(eta[t]),
-                    bias_correct=bool(plan.bias_correct),
-                    wmasks=wmasks, ctx=prep.ctx)
-                tracer.count("h2d_bytes", prep.h2d_bytes)
-                prep.release()       # free this round's double-buffer slot
-                elapsed += plan.elapsed
-                if tracer.active:
-                    # the clock-model ledger row: planned deadline vs
-                    # simulated clock vs measured wall vs the model's view
-                    jax.block_until_ready(params)
-                    wall_now = obs.now()
-                    tracer.count(
-                        "batch_elements_real",
-                        int(np.minimum(np.asarray(plan.batch_sizes,
-                                                  np.float64)[:U_act],
-                                       float(s_max)).sum()))
-                    tracer.count("batch_elements_padded", U_pad * s_max)
-                    tracer.gauge("cohort_size", U_act)
-                    tracer.event("round", **obs.round_record(
-                        t=t, plan=plan, cfg=view_cfg, L=model.L,
-                        U_act=U_act, U_pad=U_pad, s_max=s_max,
-                        sim_total=elapsed,
-                        wall_round_s=wall_now - wall_round0,
-                        wall_total_s=wall_now - wall_start,
-                        available=available,
-                        carry=getattr(backend, "last_carry", None) or None,
-                        regions=getattr(backend, "last_regions",
-                                        None) or None))
-                if (t % eval_every == 0) or (t == rounds - 1):
-                    with tracer.span("eval"):
-                        acc, loss = eval_fn(params)
+                with obs.annotate("round", round=t + 1):
+                    tracer.set_round(t + 1)
+                    wall_round0 = obs.now() if tracer.active else 0.0
+                    if pending is not None:
+                        t0 = obs.now()
+                        prep: _Prepared = pending.result()
+                        pending = None
                         if tracer.active:
-                            # explicit telemetry sync: the span should
-                            # measure eval compute, not async dispatch
-                            jax.block_until_ready((acc, loss))
-                    hist.times.append(elapsed)
-                    hist.rounds.append(t + 1)
-                    hist.accuracy.append(acc)
-                    hist.deadlines.append(float(plan.elapsed))
-                    hist.train_loss.append(loss)
-                    if available is not None:
-                        hist.available.append(int(available))
-                    pending_evals.append(len(hist.accuracy) - 1)
-                    if tracer.active or verbose:
-                        # ONE record for the sink and the console, rendered
-                        # from exactly what History keeps — only this
-                        # report boundary pays the float() conversion
-                        drain_evals()
-                        rec = {"round": t + 1, "available": available,
-                               "cohort": U_act, "sim_total": elapsed,
-                               "T_deadline": float(plan.elapsed),
-                               "acc": hist.accuracy[-1],
-                               "loss": hist.train_loss[-1]}
-                        tracer.event("eval", **rec)
+                            t_res = obs.now()
+                            tracer.count("dispatch_wait_s",
+                                         round(t_res - t0, 6))
+                            tracer.count("prefetch_rounds", 1)
+                            if dispatch_t0 is not None:
+                                # worker wall time hidden behind the
+                                # device dispatch window of the
+                                # previous round
+                                lo = max(prep.t_start, dispatch_t0)
+                                hi = min(prep.t_end, t_res)
+                                if hi > lo:
+                                    tracer.count("prefetch_overlap_s",
+                                                 round(hi - lo, 6))
+                    else:
+                        prep = plan_round(t)
+                    for name, s0, dur, attrs in prep.spans:
+                        tracer.span_record(name, s0, dur, **attrs)
+                    if prep.replan is not None:
+                        hist.replans.append(prep.replan)
+                        tracer.event("replan", **prep.replan)
+                        drain()      # replan events report live state
                         if verbose:
-                            print(obs.format_eval(hist.method, rec))
-                if on_round is not None:
-                    drain_evals()    # hooks read materialized History
-                    with tracer.span("checkpoint"):
-                        on_round(t, params, hist)
+                            print(obs.format_replan(hist.method,
+                                                    prep.replan))
+                    if prep.kind == "skip":
+                        continue
+                    if prep.kind == "stop":
+                        break
+                    plan, U_act = prep.plan, prep.U_act
+                    wmasks = None
+                    if plan.width_ratios is not None:
+                        # HeteroFL masks read the LIVE params tree — the
+                        # one stack input the planner cannot speculate on
+                        with tracer.span("stack", part="wmasks"):
+                            wmasks = self._width_masks(
+                                params, plan.width_ratios, U_pad)
+                    if prefetch and prep.replan is None and \
+                            t + 1 < rounds:
+                        # overlap round t+1's host phases with round t's
+                        # device step; a replan round forces the next
+                        # plan inline (and a skip `continue`s before
+                        # this point)
+                        pending = pool.submit(plan_ahead, t + 1)
+                    if prefetch and not warmed:
+                        # AOT warm-up: compile+execute the round step
+                        # and the eval step on dummies before round 0
+                        # dispatches, so trace cost never lands inside
+                        # a measured round
+                        t0 = obs.now()
+                        with tracer.span("warm_up"):
+                            backend.warm_up(
+                                params, prep.xb, prep.yb, prep.wb,
+                                prep.mask, plan.p, jnp.float32(eta[t]),
+                                bias_correct=bool(plan.bias_correct),
+                                wmasks=wmasks, ctx=prep.ctx)
+                            dummy = jax.tree.map(
+                                lambda a: jnp.zeros(jnp.shape(a),
+                                                    jnp.result_type(a)),
+                                params)
+                            jax.block_until_ready(eval_fn(dummy))
+                        tracer.count("warm_up_s", round(obs.now() - t0, 6))
+                        warmed = True
+                    available = prep.cohort.available
+                    dispatch_t0 = obs.now()
+                    params = backend.run_round(
+                        params, prep.xb, prep.yb, prep.wb, prep.mask,
+                        plan.p, jnp.float32(eta[t]),
+                        bias_correct=bool(plan.bias_correct),
+                        wmasks=wmasks, ctx=prep.ctx)
+                    tracer.count("h2d_bytes", prep.h2d_bytes)
+                    prep.release()   # free this round's buffer slot
+                    elapsed += plan.elapsed
+                    if tracer.active:
+                        # the clock-model ledger row: planned deadline
+                        # vs simulated clock vs measured wall vs the
+                        # model's view
+                        walls = {"wall_round_s": None,
+                                 "wall_total_s": None}
+                        if tracer.sync:
+                            jax.block_until_ready(params)
+                            wall_now = obs.now()
+                            walls = {
+                                "wall_round_s": wall_now - wall_round0,
+                                "wall_total_s": wall_now - wall_start}
+                        tracer.count("batch_elements_padded",
+                                     U_pad * s_max)
+                        row = functools.partial(
+                            emit_round, t=t, plan=plan,
+                            cfg=prep.view_cfg, U_act=U_act,
+                            sim_total=elapsed, available=available,
+                            carry=getattr(backend, "last_carry",
+                                          None) or None,
+                            regions=getattr(backend, "last_regions",
+                                            None) or None, **walls)
+                        if tracer.sync:
+                            row()
+                        else:
+                            deferred.append(row)
+                    if (t % eval_every == 0) or (t == rounds - 1):
+                        with tracer.span("eval"):
+                            acc, loss = eval_fn(params)
+                            if tracer.sync:
+                                # explicit telemetry sync: the span
+                                # should measure eval compute, not
+                                # async dispatch
+                                jax.block_until_ready((acc, loss))
+                        hist.times.append(elapsed)
+                        hist.rounds.append(t + 1)
+                        hist.accuracy.append(acc)
+                        hist.deadlines.append(float(plan.elapsed))
+                        hist.train_loss.append(loss)
+                        if available is not None:
+                            hist.available.append(int(available))
+                        pending_evals.append(len(hist.accuracy) - 1)
+                        if tracer.active or verbose:
+                            rec = functools.partial(emit_eval, {
+                                "round": t + 1, "available": available,
+                                "cohort": U_act, "sim_total": elapsed,
+                                "T_deadline": float(plan.elapsed)},
+                                len(hist.accuracy) - 1)
+                            if tracer.sync or verbose:
+                                # only this report boundary pays the
+                                # float() conversion
+                                drain()
+                                rec()
+                            else:
+                                deferred.append(rec)
+                    if on_round is not None:
+                        drain()    # hooks read materialized History
+                        with tracer.span("checkpoint"):
+                            on_round(t, params, hist)
         finally:
             if pool is not None:
                 if pending is not None:
                     pending.cancel()
                 pool.shutdown(wait=True)
-        drain_evals()
+        drain()
         tracer.set_round(None)
         if tracer.active:
             hist.telemetry = tracer.summary()

@@ -47,8 +47,10 @@ def _pad_features(x: jnp.ndarray, block_f: int) -> tuple[jnp.ndarray, int]:
 
 
 def _fold(grads: jnp.ndarray, weights: jnp.ndarray, kernel, out_dtype,
-          block_f: int, interpret: bool) -> jnp.ndarray:
-    """Shared pallas_call: (L, U, F) x (L, U, k) weight columns -> (L, F)."""
+          block_f: int, interpret: bool, name: str) -> jnp.ndarray:
+    """Shared pallas_call: (L, U, F) x (L, U, k) weight columns -> (L, F).
+    ``name`` is the kernel's name in the compiled program and the device
+    trace, kept stable so a reader can find the kernel by it."""
     L, U, F = grads.shape
     grads, bf = _pad_features(grads, block_f)
     Fp = grads.shape[-1]
@@ -63,6 +65,7 @@ def _fold(grads: jnp.ndarray, weights: jnp.ndarray, kernel, out_dtype,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name=name,
     )(grads, *[w.astype(jnp.float32)[..., None] for w in weights])
     return out[:, 0, :F]
 
@@ -81,7 +84,8 @@ def adel_agg(grads: jnp.ndarray, coeff: jnp.ndarray, *,
     Arbitrary F is supported: the flattened feature dim is zero-padded up to
     a ``block_f`` multiple for the kernel grid and the output sliced back.
     """
-    return _fold(grads, [coeff], _kernel, grads.dtype, block_f, interpret)
+    return _fold(grads, [coeff], _kernel, grads.dtype, block_f, interpret,
+                 "adel_agg")
 
 
 def _kernel_q8(q_ref, s_ref, c_ref, o_ref):
@@ -106,4 +110,4 @@ def adel_agg_q8(q: jnp.ndarray, scales: jnp.ndarray, coeff: jnp.ndarray, *,
     delta tree is never materialized per client.
     """
     return _fold(q, [scales, coeff], _kernel_q8, jnp.float32, block_f,
-                 interpret)
+                 interpret, "adel_agg_q8")

@@ -218,8 +218,11 @@ def main(argv=None):
                          "render with python -m repro.obs.timeline")
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
                     help="capture a jax.profiler device trace of the whole "
-                         "run into DIR (view with TensorBoard / Perfetto); "
-                         "a profiler that fails fails the run")
+                         "run into DIR (view with TensorBoard / Perfetto), "
+                         "with the program's repro.* spans on its clock; "
+                         "--events then records without syncing, so the "
+                         "trace shows the real overlap. A profiler that "
+                         "fails fails the run")
     args = ap.parse_args(argv)
     use_compile_cache()
     replan = args.replan
@@ -230,7 +233,9 @@ def main(argv=None):
                  args.regions)
     pspec = (PopulationSpec.from_cli(args)
              if any(v is not None for v in pop_flags) else None)
-    tracer = obs.make_tracer(args.events)
+    # under the profiler the device timeline comes from the trace: a tracer
+    # that synced every round would serialize what the trace should show
+    tracer = obs.make_tracer(args.events, sync=not args.profile_dir)
     t0 = obs.now()
     profile = (jax.profiler.trace(args.profile_dir) if args.profile_dir
                else contextlib.nullcontext())

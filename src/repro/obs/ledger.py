@@ -9,7 +9,8 @@ round, the three clocks that model implies and the two it cannot see:
 
 * ``T_deadline``    — the planned deadline ``T_t`` (what the solver spent),
 * ``sim_total``     — the simulated R1/R2 clock after the round,
-* ``wall_round_s``  — measured host wall time of the round (monotonic).
+* ``wall_round_s``  — measured host wall time of the round (monotonic;
+  None under a tracer that does not sync, which cannot time the device).
   Under the prefetch pipeline (``ExecSpec.pipeline="prefetch"``) the
   round's host planning phases ran DURING the previous round's device
   step, so ``wall_round_s`` covers only consume + dispatch + device work;
@@ -63,8 +64,8 @@ def expected_depth(lam: np.ndarray, L: int) -> np.ndarray:
 
 
 def round_record(*, t: int, plan, cfg, L: int, U_act: int, U_pad: int,
-                 s_max: int, sim_total: float, wall_round_s: float,
-                 wall_total_s: float, available=None, carry=None,
+                 s_max: int, sim_total: float, wall_round_s: float | None,
+                 wall_total_s: float | None, available=None, carry=None,
                  regions=None) -> dict:
     """One clock-model ledger row for executed round ``t`` (0-based).
 
@@ -99,8 +100,10 @@ def round_record(*, t: int, plan, cfg, L: int, U_act: int, U_pad: int,
         "T_deadline": T_t,
         "sim_round": T_t,
         "sim_total": float(sim_total),
-        "wall_round_s": round(float(wall_round_s), 6),
-        "wall_total_s": round(float(wall_total_s), 6),
+        "wall_round_s": (None if wall_round_s is None
+                         else round(float(wall_round_s), 6)),
+        "wall_total_s": (None if wall_total_s is None
+                         else round(float(wall_total_s), 6)),
         "cohort": int(U_act),
         "padded": int(U_pad),
         "batch_real": int(np.minimum(S, float(s_max)).sum()),
@@ -180,11 +183,11 @@ def drift_summary(rows) -> dict:
     if drifts:
         out["depth_drift_mean"] = round(float(np.mean(drifts)), 4)
         out["depth_drift_max_abs"] = round(float(np.max(np.abs(drifts))), 4)
-    walls = np.asarray([r["wall_round_s"] for r in rows], np.float64)
-    sims = np.asarray([r["sim_round"] for r in rows], np.float64)
-    ok = sims > 0
-    if ok.any():
-        per = walls[ok] / sims[ok]
+    timed = [r for r in rows if r.get("wall_round_s") is not None
+             and r["sim_round"] > 0]
+    if timed:
+        per = np.asarray([r["wall_round_s"] / r["sim_round"]
+                          for r in timed], np.float64)
         out["wall_per_sim_mean"] = round(float(per.mean()), 6)
         out["wall_per_sim_max"] = round(float(per.max()), 6)
     clients = sum(r["cohort"] for r in rows)

@@ -5,9 +5,9 @@
 Three sections per stream:
 
 * **phase timeline** — per-round wall-seconds by phase (``cohort`` /
-  ``replan`` / ``plan`` / ``stack`` / ``local_train`` / ``aggregate`` /
-  ``eval`` / ``checkpoint``), i.e. where each round's host time actually
-  went;
+  ``replan`` / ``plan`` / ``stack`` / ``round_step`` / ``local_train`` /
+  ``aggregate`` / ``eval`` / ``checkpoint``), i.e. where each round's host
+  time actually went;
 * **clock-model ledger** — per-round planned deadline ``T_t``, simulated
   clock, measured wall time, and the exponential model's predictions
   (full-depth completion time, expected backprop depth) against the round's
@@ -21,11 +21,13 @@ shows per-round bytes on the wire versus the dense-float32 logical payload
 and the resulting compression ratio.
 
 Prefetch-pipelined runs (``ExecSpec.pipeline="prefetch"``) add a fifth
-section from the round driver's pipeline counters: per-round H2D bytes of
-the stacked batches (``h2d_bytes``), worker planning time hidden behind
-the device step (``prefetch_overlap_s``), main-thread stalls on the
-prefetch future (``dispatch_wait_s``), and the one-off AOT warm-up cost
-(``warm_up_s``).
+section from the round driver's pipeline counters: per-round bytes the
+runtime copied from host memory to the device (``h2d_bytes``: the cohort,
+plan and learning-rate arrays not already on the device, so a few bytes a
+round where the cohort and the plan live there), worker planning time
+hidden behind the device step (``prefetch_overlap_s``), main-thread
+stalls on the prefetch future (``dispatch_wait_s``), and the one-off AOT
+warm-up cost (``warm_up_s``).
 """
 from __future__ import annotations
 
@@ -79,9 +81,10 @@ def _fmt_bytes(b: float) -> str:
 
 BYTE_COUNTERS = ("aggregate_bytes_logical", "aggregate_bytes_wire")
 
-# the prefetch round driver's counters (repro.fl.runtime): stacked-batch
-# H2D bytes, worker planning time hidden behind the device step, main-
-# thread stalls on the prefetch future, and the one-off AOT warm-up cost
+# the prefetch round driver's counters (repro.fl.runtime): bytes copied
+# from host memory, worker planning time hidden behind the device step,
+# main-thread stalls on the prefetch future, and the one-off AOT warm-up
+# cost
 PIPELINE_COUNTERS = ("h2d_bytes", "prefetch_overlap_s", "dispatch_wait_s",
                      "warm_up_s")
 
@@ -138,7 +141,8 @@ def render(records: list[dict], *, title: str = "") -> str:
                 str(r.get("round", r.get("t", 0) + 1)),
                 f"{r['T_deadline']:.3f}",
                 f"{r['sim_total']:.2f}",
-                f"{r['wall_round_s']:.3f}",
+                (f"{r['wall_round_s']:.3f}"
+                 if r.get("wall_round_s") is not None else "—"),
                 (f"{r['pred_full_s']:.3f}" if "pred_full_s" in r else "—"),
                 (f"{r['depth_pred']:.2f}" if "depth_pred" in r else "—"),
                 f"{r['depth_real']:.2f}",
@@ -238,8 +242,8 @@ def render(records: list[dict], *, title: str = "") -> str:
                      _fmt_ms(tot["prefetch_overlap_s"]),
                      _fmt_ms(tot["dispatch_wait_s"]),
                      _fmt_ms(tot["warm_up_s"])])
-        out.append("\n-- pipeline (H2D bytes, hidden planning ms, prefetch "
-                   "stall ms, warm-up ms) --")
+        out.append("\n-- pipeline (host-to-device bytes, hidden planning "
+                   "ms, prefetch stall ms, warm-up ms) --")
         out.append(_table(["round", "h2d", "overlap", "stall", "warm_up"],
                           rows))
     if len(out) <= (1 if title else 0):

@@ -1,14 +1,15 @@
-"""Lightweight, dependency-free tracing core for the round runtime.
+"""Lightweight tracing core for the round runtime.
 
 A :class:`Tracer` records three kinds of structured events:
 
 * **phase spans** — nestable, monotonic-clock timed sections
-  (``plan`` / ``cohort`` / ``stack`` / ``local_train`` / ``aggregate`` /
-  ``eval`` / ``replan`` / ``checkpoint``), emitted on span exit with
-  duration, nesting depth, parent phase, and a global sequence number;
-* **typed counters / gauges** — monotonically accumulated counts
-  (padded-vs-real batch elements, bytes aggregated per backend, replan
-  solver steps) and last-value gauges (cohort size);
+  (``plan`` / ``cohort`` / ``stack`` / ``round_step`` / ``local_train`` /
+  ``aggregate`` / ``eval`` / ``replan`` / ``checkpoint``), emitted on span
+  exit with duration, nesting depth, parent phase, and a global sequence
+  number;
+* **typed counters** — monotonically accumulated counts (padded-vs-real
+  batch elements, bytes aggregated per backend, bytes copied from host
+  memory to the device);
 * **ledger events** — one ``kind="round"`` record per executed round
   carrying the clock-model ledger fields (:mod:`repro.obs.ledger`:
   deadline ``T_t`` vs simulated round time vs measured host wall time,
@@ -19,12 +20,21 @@ Every record is a plain dict fanned out to the attached sinks:
 ``python -m repro.obs.timeline`` input), :class:`MemorySink` keeps them in
 a list (tests, in-process consumers).
 
-The default tracer everywhere is the :data:`NULL_TRACER` singleton — every
-method is a no-op, ``active`` is False so instrumented call sites skip
+Every phase span, with or without a tracer, also opens a
+``jax.profiler.TraceAnnotation`` named ``repro.<phase>`` (:func:`annotate`,
+the one place that names them), so a profiler trace shows the program's
+own phases on the device trace's clock. With no profiler running an
+annotation costs well under a microsecond.
+
+The default tracer everywhere is the :data:`NULL_TRACER` singleton — it
+records nothing, ``active`` is False so instrumented call sites skip
 record construction entirely, and trajectories are bit-identical with or
-without it (tracing never touches PRNG keys or numerics; an active tracer
-only adds ``jax.block_until_ready`` fences so span durations measure real
-device work instead of async dispatch).
+without it (tracing never touches PRNG keys or numerics). An active
+tracer with ``sync=True`` (the default) adds ``jax.block_until_ready``
+fences so span durations and the ledger's wall times measure device work
+instead of async dispatch; ``sync=False`` adds none, converts no device
+value, and leaves the wall fields empty, for runs whose device timeline
+comes from a profiler trace.
 
 All span timing flows through :func:`now` (``time.perf_counter``) — the
 monotonic clock benchmark call sites share, so recorded durations are
@@ -32,21 +42,42 @@ NTP-proof.
 """
 from __future__ import annotations
 
+import functools
 import io
 import json
 import os
 import time
 from typing import Any, Callable, Optional
 
-__all__ = ["now", "PHASES", "Sink", "MemorySink", "JsonlSink", "Span",
-           "Tracer", "NullTracer", "NULL_TRACER", "make_tracer",
-           "tree_bytes"]
+from jax.profiler import TraceAnnotation, annotate_function
+
+__all__ = ["now", "PHASES", "annotate", "annotated", "Sink", "MemorySink",
+           "JsonlSink", "Span", "Tracer", "NullTracer", "NULL_TRACER",
+           "make_tracer"]
 
 # canonical phase order of one federated round (timeline rendering order);
 # warm_up is the pre-round-0 AOT trace/compile/execute of the round + eval
-# steps (prefetch pipeline), charged to the round that triggered it
-PHASES = ("warm_up", "cohort", "replan", "plan", "stack", "local_train",
-          "aggregate", "eval", "checkpoint")
+# steps (prefetch pipeline), charged to the round that triggered it;
+# round_step is the fused backends' single dispatch (train + fold + apply),
+# local_train / aggregate the chunked backends' separate calls
+PHASES = ("warm_up", "cohort", "replan", "plan", "stack", "round_step",
+          "local_train", "aggregate", "eval", "checkpoint")
+
+
+def annotate(name: str, **attrs) -> TraceAnnotation:
+    """The profiler's host span of phase ``name``: a
+    ``jax.profiler.TraceAnnotation`` called ``repro.<name>``, carrying
+    ``attrs``. Besides the phase spans, the runtime opens ``repro.run``
+    around a run, ``repro.round`` (``round=t``) around each round,
+    ``repro.prefetch`` on the worker that plans the next round, and the
+    policies ``repro.draw`` around the straggler draw."""
+    return TraceAnnotation(f"repro.{name}", **attrs)
+
+
+def annotated(name: str) -> Callable:
+    """Decorator: every call of the function runs under
+    :func:`annotate` ``(name)``."""
+    return functools.partial(annotate_function, name=f"repro.{name}")
 
 
 def now() -> float:
@@ -57,12 +88,6 @@ def now() -> float:
     are not trustworthy on shared CI runners.
     """
     return time.perf_counter()
-
-
-def tree_bytes(tree: Any) -> int:
-    """Total buffer bytes across a pytree's array leaves."""
-    import jax
-    return sum(getattr(leaf, "nbytes", 0) for leaf in jax.tree.leaves(tree))
 
 
 def _json_default(o):
@@ -118,7 +143,7 @@ class JsonlSink(Sink):
 class Span:
     """One nestable timed phase; emitted as a record when the span exits."""
 
-    __slots__ = ("_tr", "name", "attrs", "t0")
+    __slots__ = ("_tr", "name", "attrs", "t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tr = tracer
@@ -127,12 +152,15 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._tr._stack.append(self.name)
+        self._ann = annotate(self.name, **self.attrs)
+        self._ann.__enter__()
         self.t0 = self._tr.clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         tr = self._tr
         t1 = tr.clock()
+        self._ann.__exit__(*exc)
         tr._stack.pop()
         rec = {"kind": "span", "name": self.name,
                "round": tr._round,
@@ -148,28 +176,34 @@ class Span:
 
 
 class Tracer:
-    """Collects spans / counters / gauges / events and fans them out to
-    sinks, while aggregating an in-memory summary (per-phase totals,
-    counter totals, the per-round clock-model ledger).
+    """Collects spans / counters / events and fans them out to sinks, while
+    aggregating an in-memory summary (per-phase totals, counter totals, the
+    per-round clock-model ledger).
 
     ``clock`` is injectable for deterministic tests; it defaults to the
-    monotonic :func:`now`.
+    monotonic :func:`now`. ``sync`` (default True) lets instrumented call
+    sites block on device results so spans and the ledger's wall fields
+    measure device work; with ``sync=False`` they never block and never
+    convert a device value, and the records that need device values
+    (ledger rows, eval results) are emitted when the runtime next
+    materializes them for its own reasons.
     """
 
     active = True
 
-    def __init__(self, sinks: Any = (), *, clock: Callable[[], float] = now):
+    def __init__(self, sinks: Any = (), *, clock: Callable[[], float] = now,
+                 sync: bool = True):
         if isinstance(sinks, Sink):
             sinks = (sinks,)
         self.sinks: list[Sink] = list(sinks)
         self.clock = clock
+        self.sync = bool(sync)
         self._stack: list[str] = []
         self._seq = 0
         self._round: Optional[int] = None
         # aggregated summary state
         self.phase_totals: dict[str, dict] = {}
         self.counters: dict[str, float] = {}
-        self.gauges: dict[str, float] = {}
         self.rounds: list[dict] = []       # kind="round" ledger records
 
     # ------------------------------------------------------------------
@@ -217,11 +251,6 @@ class Tracer:
         self._emit({"kind": "count", "name": name, "round": self._round,
                     "value": value, **attrs})
 
-    def gauge(self, name: str, value: float, **attrs) -> None:
-        self.gauges[name] = value
-        self._emit({"kind": "gauge", "name": name, "round": self._round,
-                    "value": value, **attrs})
-
     def event(self, kind: str, **fields) -> None:
         rec = {"kind": kind, "round": self._round, **fields}
         if kind == "round":
@@ -243,7 +272,6 @@ class Tracer:
                 "counters": {k: (int(v) if float(v).is_integer() else
                                  round(float(v), 6))
                              for k, v in self.counters.items()},
-                "gauges": {k: float(v) for k, v in self.gauges.items()},
                 "ledger": ledger,
                 "drift": drift_summary(self.rounds)}
 
@@ -252,43 +280,29 @@ class Tracer:
             s.close()
 
 
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class NullTracer:
     """Do-nothing tracer: the zero-overhead default everywhere.
 
-    ``active`` is False so instrumented call sites skip building records /
-    blocking on device results entirely; the remaining per-call cost is one
-    attribute check plus a no-op context manager.
+    ``active`` and ``sync`` are False so instrumented call sites skip
+    building records / blocking on device results entirely; a span is only
+    its profiler annotation (:func:`annotate`), so a profiler trace shows
+    the phases of an untraced run too.
     """
 
     active = False
+    sync = False
 
     def set_round(self, t) -> None:
         pass
 
-    def span(self, name: str, **attrs) -> _NullSpan:
-        return _NULL_SPAN
+    def span(self, name: str, **attrs) -> TraceAnnotation:
+        return annotate(name, **attrs)
 
     def span_record(self, name: str, t0: float, dur_s: float,
                     **attrs) -> None:
         pass
 
     def count(self, name: str, value: float = 1, **attrs) -> None:
-        pass
-
-    def gauge(self, name: str, value: float, **attrs) -> None:
         pass
 
     def event(self, kind: str, **fields) -> None:
@@ -304,11 +318,11 @@ class NullTracer:
 NULL_TRACER = NullTracer()
 
 
-def make_tracer(events: Optional[str] = None, *,
-                sinks: Any = None) -> Tracer | NullTracer:
+def make_tracer(events: Optional[str] = None, *, sinks: Any = None,
+                sync: bool = True) -> Tracer | NullTracer:
     """Convenience constructor for CLIs / benchmarks: a :class:`Tracer`
-    writing JSONL to ``events`` (and/or the given sinks), or the
-    :data:`NULL_TRACER` when neither is given."""
+    (``sync`` as there) writing JSONL to ``events`` (and/or the given
+    sinks), or the :data:`NULL_TRACER` when neither is given."""
     out: list[Sink] = []
     if events:
         out.append(JsonlSink(events))
@@ -316,4 +330,4 @@ def make_tracer(events: Optional[str] = None, *,
         out.extend((sinks,) if isinstance(sinks, Sink) else list(sinks))
     if not out:
         return NULL_TRACER
-    return Tracer(out)
+    return Tracer(out, sync=sync)

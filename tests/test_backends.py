@@ -8,7 +8,9 @@ The multi-device shard_map case needs ``XLA_FLAGS=
 --xla_force_host_platform_device_count=N`` set BEFORE jax initializes, so it
 runs in a subprocess (>= 4 host devices, per the acceptance criteria)."""
 import argparse
+import json
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -466,3 +468,86 @@ def test_shard_map_multi_device_subprocess():
                           capture_output=True, text=True, timeout=900)
     assert proc.returncode == 0 and "MULTIDEV_OK" in proc.stdout, (
         proc.stdout + "\n" + proc.stderr)
+
+
+# -- named scopes inside the fused round steps --------------------------------
+# each backend's step under the four aggregation rules it compiles
+STEP_BRANCHES = {"jnp": {}, "pallas": dict(agg_impl="pallas"),
+                 "int8": dict(compression="int8"), "hetero": {}}
+
+
+def _step_scopes(backend, branch, U=4, s=4):
+    """The scope segments (between slashes) of the op_name metadata of one
+    compiled round step of the MLP, at small shapes."""
+    model = make_mlp()
+    bk = make_backend(backend, model, **STEP_BRANCHES[branch])
+    params = model.init(jax.random.PRNGKey(0))
+    L = model.L
+    hetero = branch == "hetero"
+    wm = (model.width_masks(params, np.asarray([1.0, 0.5, 0.25, 1.0][:U],
+                                               np.float32))
+          if hetero else None)
+    args = (params, jnp.zeros((U, s, 28, 28, 1)), jnp.zeros((U, s), jnp.int32),
+            jnp.full((U, s), 1.0 / s), jnp.ones((U, L)), jnp.zeros((L,)),
+            jnp.float32(0.1), wm)
+    text = bk._step(True, hetero).lower(*args).compile().as_text()
+    return _scope_segments(text)
+
+
+def _scope_segments(text: str) -> dict:
+    """{scope: op_names under it} from compiled HLO text."""
+    out: dict = {}
+    for name in set(re.findall(r'op_name="([^"]*)"', text)):
+        for seg in name.split("/"):
+            out.setdefault(seg, []).append(name)
+    return out
+
+
+@pytest.mark.parametrize("branch", list(STEP_BRANCHES))
+@pytest.mark.parametrize("backend", ["dense", "temporal"])
+def test_round_step_named_scopes(backend, branch):
+    """The compiled step's ops say what they belong to: each client's local
+    update (its backward under transpose(jvp(...))), the Eq. 5 fold and the
+    server's apply."""
+    scopes = _step_scopes(backend, branch)
+    for scope in ("local_train", "fold", "server_apply"):
+        assert scope in scopes, (scope, sorted(scopes))
+    assert any("transpose(jvp" in n for n in scopes["local_train"])
+    assert "exchange" not in scopes
+
+
+_SCOPES_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=4")
+    import json, sys
+    sys.path.insert(0, os.path.join(os.environ["REPO"], "tests"))
+    import jax
+    assert len(jax.devices()) == 4, jax.devices()
+    from test_backends import STEP_BRANCHES, _step_scopes
+    print("SCOPES " + json.dumps({
+        b: sorted(_step_scopes("shard_map", b)) for b in STEP_BRANCHES
+        if b != "pallas"}))     # shard_map folds with jnp inside the map
+""")
+
+
+@pytest.fixture(scope="module")
+def shard_map_scopes():
+    env = dict(os.environ, REPO=REPO, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.path.join(REPO, "src") + os.pathsep + \
+        env.get("PYTHONPATH", "")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run([sys.executable, "-c", _SCOPES_SCRIPT], env=env,
+                          capture_output=True, text=True, timeout=600)
+    lines = [ln for ln in proc.stdout.splitlines()
+             if ln.startswith("SCOPES ")]
+    assert proc.returncode == 0 and lines, proc.stdout + proc.stderr
+    return json.loads(lines[-1][len("SCOPES "):])
+
+
+@pytest.mark.parametrize("branch", ["jnp", "int8", "hetero"])
+def test_shard_map_step_named_scopes(shard_map_scopes, branch):
+    """shard_map over four host devices: its psums run under ``exchange``
+    beside local_train, fold and server_apply."""
+    for scope in ("local_train", "fold", "exchange", "server_apply"):
+        assert scope in shard_map_scopes[branch], scope

@@ -1,6 +1,7 @@
 """Telemetry (repro.obs): span invariants, NullTracer overhead budget,
-tracing-on == tracing-off trajectories on every backend, the History
-round-trip fix, the clock-model ledger math, and the timeline renderer."""
+tracing-on == tracing-off trajectories on every backend (with and without
+syncing), the profiler annotations' nesting, the History round-trip fix,
+the clock-model ledger math, and the timeline renderer."""
 import json
 import os
 
@@ -16,8 +17,9 @@ from repro.core.scheduler import solve
 from repro.core.types import AnalysisConfig
 from repro.data.synthetic import make_image_dataset
 from repro.fl.partition import dirichlet_partition, stack_clients
-from repro.fl.runtime import History
+from repro.fl.runtime import History, RoundRuntime, StaticCohortSource
 from repro.fl.server import run_federated
+from repro.fl.spec import ExecSpec
 from repro.models.paper_models import make_mlp
 from repro.obs.ledger import drift_summary, expected_depth, phase_table
 from repro.obs.timeline import load_events, render
@@ -98,11 +100,13 @@ def test_tracer_summary_aggregates():
         pass
     tr.count("batch_elements_real", 10)
     tr.count("batch_elements_real", 5)
-    tr.gauge("cohort_size", 8)
+    tr.count("dispatch_wait_s", 0.25)
+    tr.count("dispatch_wait_s", 0.125)
     s = tr.summary()
     assert s["phases"]["plan"]["count"] == 2
     assert s["counters"]["batch_elements_real"] == 15
-    assert s["gauges"]["cohort_size"] == 8.0
+    assert s["counters"]["dispatch_wait_s"] == 0.375
+    assert set(s) == {"phases", "counters", "ledger", "drift"}
     json.dumps(s)  # summary must be JSON-clean
 
 
@@ -139,7 +143,7 @@ def test_null_tracer_overhead_budget(setup):
         with null.span("plan", backend="dense"):
             pass
         null.count("batch_elements_real", 7)
-        null.gauge("cohort_size", 8)
+        null.count("h2d_bytes", 4)
         null.event("round", t=0)
         null.active  # the hot-path guard itself
     per_group = (obs.now() - t0) / n
@@ -157,12 +161,12 @@ def test_null_tracer_overhead_budget(setup):
 
 def test_null_tracer_api_is_inert():
     null = obs.NULL_TRACER
-    assert null.active is False
+    assert null.active is False and null.sync is False
     with null.span("anything", junk=1) as sp:
         assert sp is not None
     null.set_round(3)
     null.count("x")
-    null.gauge("y", 1.0)
+    null.count("y", 1.0)
     null.event("round", t=0)
     assert null.summary() == {}
     null.close()
@@ -172,27 +176,131 @@ def test_null_tracer_api_is_inert():
 # tracing on == tracing off, every backend
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("sync", [True, False])
 @pytest.mark.parametrize("backend", ["dense", "chunked", "shard_map",
                                      "temporal"])
-def test_tracing_preserves_trajectories(setup, backend):
+def test_tracing_preserves_trajectories(setup, backend, sync):
     """Identical History with tracing on vs off — telemetry must never
-    touch PRNG keys or numerics, on any execution backend."""
+    touch PRNG keys or numerics, on any execution backend, whether or not
+    the tracer syncs."""
     base = _run(setup, backend, tracer=None)
-    tr = obs.Tracer(obs.MemorySink())
+    tr = obs.Tracer(obs.MemorySink(), sync=sync)
     traced = _run(setup, backend, tracer=tr)
     a, b = base.as_dict(), traced.as_dict()
     tel = b.pop("telemetry")
     a.pop("telemetry")
     assert a == b
-    # and the traced run actually recorded its rounds
-    assert tel["phases"]["local_train"]["count"] >= len(traced.rounds)
+    # and the traced run actually recorded its rounds: the fused backends
+    # under one round_step span, the chunked one under local_train
+    step = "local_train" if backend == "chunked" else "round_step"
+    assert tel["phases"][step]["count"] >= len(traced.rounds)
     assert len(tel["ledger"]) == len(traced.rounds)
+    assert [r["round"] for r in tel["ledger"]] == traced.rounds
     assert tel["counters"]["batch_elements_real"] > 0
+    # a tracer that does not sync cannot time the device
+    assert all((r["wall_round_s"] is None) == (not sync)
+               for r in tel["ledger"])
+
+
+@pytest.mark.parametrize("pipeline", ["serial", "prefetch"])
+def test_unsynced_tracer_never_blocks(setup, monkeypatch, pipeline):
+    """A tracer with sync=False adds no block_until_ready to a run (the
+    prefetch warm-up's own blocks stay), yet emits every ledger and eval
+    record, each stamped with its own round."""
+    calls = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: calls.append(1) or real(x))
+    model, cfg, data, schedule = setup
+    runs = {}
+    for name, tracer in (("null", None),
+                         ("unsynced", obs.Tracer(obs.MemorySink(),
+                                                 sync=False)),
+                         ("synced", obs.Tracer(obs.MemorySink()))):
+        calls.clear()
+        policy = make_policy("adel", cfg, schedule=schedule)
+        _, hist = run_federated(model, policy, cfg, *data,
+                                key=jax.random.PRNGKey(0),
+                                exec=ExecSpec(pipeline=pipeline),
+                                tracer=tracer)
+        runs[name] = (len(calls), hist, tracer)
+    assert runs["unsynced"][0] == runs["null"][0]
+    assert runs["synced"][0] > runs["null"][0]
+    if pipeline == "serial":
+        assert runs["null"][0] == 0
+    hist, tr = runs["unsynced"][1], runs["unsynced"][2]
+    recs = tr.sinks[0].records
+    evals = [r for r in recs if r["kind"] == "eval"]
+    assert [r["round"] for r in evals] == hist.rounds
+    assert [r["acc"] for r in evals] == hist.accuracy
+    rows = [r for r in recs if r["kind"] == "round"]
+    assert [r["round"] for r in rows] == list(range(1, len(rows) + 1))
+    real = [r for r in recs if r.get("name") == "batch_elements_real"]
+    assert [r["value"] for r in real] == [r["batch_real"] for r in rows]
+    # the ledger renders without its wall column's numbers
+    assert "clock-model ledger" in render(recs)
+    assert "wall_per_sim_mean" not in drift_summary(rows)
+
+
+def _profiled_events(tmp_path, setup, pipeline):
+    """Host events named repro.* of a two-round run under the profiler:
+    [(name, start_ns, end_ns, thread's line)]."""
+    import glob
+
+    model, cfg, data, schedule = setup
+    cx, cy, counts, x_te, y_te = data
+    runtime = RoundRuntime(model, make_policy("adel", cfg, schedule=schedule),
+                           exec=ExecSpec(pipeline=pipeline))
+    with jax.profiler.trace(str(tmp_path)):
+        runtime.run(StaticCohortSource(cx, cy, counts), rounds=2,
+                    T_max=cfg.T_max, eta=cfg.eta, s_max=16,
+                    key=jax.random.PRNGKey(0), test_x=x_te, test_y=y_te)
+    (path,) = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(path)
+    return [(e.name.split("#")[0], e.start_ns, e.start_ns + e.duration_ns,
+             (i, j))
+            for i, plane in enumerate(pd.planes)
+            if plane.name.startswith("/host")
+            for j, line in enumerate(plane.lines) for e in line.events
+            if e.name.startswith("repro.")]
+
+
+@pytest.mark.parametrize("pipeline", ["serial", "prefetch"])
+def test_profiler_spans_nest(tmp_path, setup, pipeline):
+    """Untraced, the runtime's phases still reach the profiler's trace as
+    repro.* annotations: repro.run > repro.round > repro.plan > repro.draw,
+    and repro.round_step inside its round."""
+    ev = _profiled_events(tmp_path, setup, pipeline)
+
+    def inside(name, outer, thread=True):    # nested on one thread
+        return [e for e in ev if e[0] == f"repro.{name}" and any(
+            o[0] == f"repro.{outer}" and o[1] <= e[1] and e[2] <= o[2]
+            and (o[3] == e[3] or not thread) for o in ev)]
+
+    names = [e[0] for e in ev]
+    assert names.count("repro.run") == 1
+    assert names.count("repro.round") == 2
+    assert len(inside("round", "run")) == 2
+    # prefetch also warms the step up with one dummy round, in round 1
+    warm = int(pipeline == "prefetch")
+    assert len(inside("round_step", "round")) == 2 + warm
+    assert len(inside("round_step", "warm_up")) == warm
+    assert names.count("repro.plan") == 2
+    assert len(inside("draw", "plan")) == 2
+    # round 1 is planned inline; under prefetch round 2 is planned ahead,
+    # on the worker thread, under its own repro.prefetch root
+    assert len(inside("plan", "round")) == (2 if pipeline == "serial"
+                                             else 1)
+    assert len(inside("plan", "prefetch")) == (0 if pipeline == "serial"
+                                                else 1)
+    # ... while the main thread's run is open
+    assert len(inside("prefetch", "run", thread=False)) == (
+        0 if pipeline == "serial" else 1)
 
 
 def test_chunked_splits_train_and_aggregate(setup):
     """Only the chunked backend can separate local_train from the final
-    aggregate apply; the fused backends fold both into local_train."""
+    aggregate apply; the fused backends run both under round_step."""
     sink = obs.MemorySink()
     tr = obs.Tracer(sink)
     _run(setup, "chunked", tracer=tr, chunk_size=2)

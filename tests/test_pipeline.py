@@ -6,12 +6,14 @@ close — on every backend, including the buffered backend's carry ring and
 the hierarchical backend's region folds, and across skipped rounds and
 mid-run replans (which force a serial-fallback round). The pipeline
 counters (``h2d_bytes`` / ``prefetch_overlap_s`` / ``dispatch_wait_s`` /
-``warm_up_s``) and the AOT warm-up span must land in the event stream.
+``warm_up_s``) and the AOT warm-up span must land in the event stream, and
+``h2d_bytes`` must count only what crosses from host memory.
 """
 import argparse
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core.baselines import make_policy
@@ -147,8 +149,31 @@ def test_serial_counters_absent(setup):
     c = hist.telemetry["counters"]
     assert "prefetch_rounds" not in c
     assert "warm_up_s" not in c
-    assert c["h2d_bytes"] > 0            # stacked-bytes counter is modal-
+    assert c["h2d_bytes"] > 0            # the host-bytes counter is modal-
     assert "warm_up" not in hist.telemetry["phases"]   # independent
+
+
+def test_h2d_bytes_counts_host_arrays_only(setup):
+    """h2d_bytes counts what the runtime copies from host memory: the
+    learning rate alone where the cohort and the plan live on the device,
+    plus the cohort's rows where the source hands over numpy arrays."""
+    model, cfg, data, schedule = setup
+    cx, cy, counts, x_te, y_te = data
+
+    def run(*cohort):
+        tr = Tracer(MemorySink())
+        runtime = RoundRuntime(model, make_policy("adel", cfg,
+                                                  schedule=schedule),
+                               tracer=tr)
+        runtime.run(StaticCohortSource(*cohort), rounds=R, T_max=cfg.T_max,
+                    eta=cfg.eta, s_max=16, key=jax.random.PRNGKey(0),
+                    test_x=x_te, test_y=y_te)
+        return tr.counters["h2d_bytes"], len(tr.rounds)
+
+    on_device, n = run(cx, cy, counts)
+    assert n == R and on_device == 4 * n          # one float32 a round
+    host = [np.asarray(a) for a in (cx, cy, counts)]
+    assert run(*host) == (on_device + n * sum(a.nbytes for a in host), n)
 
 
 def test_exec_spec_pipeline_validation_and_cli():

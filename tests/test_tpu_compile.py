@@ -97,7 +97,10 @@ def test_fold_kernel_compiles(one_chip, kernel, U):
     else:
         q = jax.ShapeDtypeStruct((L, U, F_FFN), jnp.int8, sharding=one_chip)
         lowered = adel_agg_q8.lower(q, w, w)
-    assert "tpu_custom_call" in lowered.compile().as_text()
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's own stable name, which a trace reader can find it by
+    assert f'/{kernel}/pallas_call"' in text
 
 
 @pytest.mark.parametrize("agg_impl", ["jnp", "pallas"])
