@@ -9,13 +9,15 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.kernels.adel_agg import adel_agg
+from repro.kernels.embed_grad import embed_grad
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_scan
 
 __all__ = ["gqa_flash", "ssd_chunked_pallas", "adel_aggregate_pallas",
-           "default_interpret"]
+           "embed_table_grad", "default_interpret"]
 
 
 def default_interpret() -> bool:
@@ -60,6 +62,21 @@ def ssd_chunked_pallas(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
                  bh.astype(jnp.float32), ch.astype(jnp.float32),
                  chunk=chunk, interpret=interpret)
     return y.reshape(B, H, S, P).transpose(0, 2, 1, 3).astype(x.dtype)
+
+
+def embed_table_grad(ids: jnp.ndarray, rows: jnp.ndarray,
+                     num_rows: int) -> jnp.ndarray:
+    """Gradient of a (num_rows, D) table from the cotangent rows (T, D) of
+    a lookup at ``ids`` (T,): their float32 sum per id. On TPU the ids are
+    sorted and the ``embed_grad`` kernel sums them; on CPU
+    ``jax.ops.segment_sum`` does. All of it runs in the ``embed_grad``
+    scope."""
+    with jax.named_scope("embed_grad"):
+        if default_interpret():
+            return jax.ops.segment_sum(rows.astype(jnp.float32), ids,
+                                       num_segments=num_rows)
+        ids, perm = lax.sort_key_val(ids, lax.iota(jnp.int32, ids.shape[0]))
+        return embed_grad(ids, rows[perm], num_rows)
 
 
 def adel_aggregate_pallas(grads, layer_ids_tree, mask, p, *,

@@ -20,6 +20,7 @@ dtype (f32 for training, bf16 for serving).
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, NamedTuple, Optional
 
 import jax
@@ -27,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.kernels import ops as kernel_ops
 from repro.models.attention import (decode_attention, gqa_attention,
                                     mla_decode, mla_prefill, rope)
 from repro.models.moe import moe_ffn
@@ -34,9 +36,9 @@ from repro.models.ssm import ssd_chunked, ssd_decode_step
 
 PyTree = Any
 
-__all__ = ["init_params", "forward", "loss_fn", "init_cache", "prefill",
-           "decode_step", "layer_ids", "param_specs", "cache_specs",
-           "count_params", "Cache"]
+__all__ = ["init_params", "embed_lookup", "forward", "loss_fn", "init_cache",
+           "prefill", "decode_step", "layer_ids", "param_specs",
+           "cache_specs", "count_params", "Cache"]
 
 
 # ---------------------------------------------------------------------------
@@ -348,6 +350,35 @@ def _run_encoder(params, cfg: ArchConfig, frames, cdt):
 # model forward / loss (train & prefill)
 # ---------------------------------------------------------------------------
 
+def embed_lookup(table: jnp.ndarray, tokens: jnp.ndarray,
+                 dtype) -> jnp.ndarray:
+    """``table[tokens]`` cast to ``dtype``: the rows are gathered from the
+    master table and then cast, which equals casting the whole table first.
+    The table's gradient is the float32 sum of the cotangent rows per token
+    (``kernel_ops.embed_table_grad``), not a scatter-add in ``dtype``."""
+    return _embed_lookup(table, tokens, jnp.dtype(dtype),
+                         (table.shape[0], jnp.dtype(table.dtype)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _embed_lookup(table, tokens, dtype, table_spec):
+    return table[tokens].astype(dtype)
+
+
+def _embed_lookup_fwd(table, tokens, dtype, table_spec):
+    return table[tokens].astype(dtype), tokens
+
+
+def _embed_lookup_bwd(dtype, table_spec, tokens, g):
+    num_rows, table_dtype = table_spec
+    grad = kernel_ops.embed_table_grad(tokens.reshape(-1),
+                                       g.reshape(-1, g.shape[-1]), num_rows)
+    return grad.astype(table_dtype), None
+
+
+_embed_lookup.defvjp(_embed_lookup_fwd, _embed_lookup_bwd)
+
+
 def forward(params: PyTree, cfg: ArchConfig, tokens: jnp.ndarray, *,
             frontend: jnp.ndarray | None = None, remat: bool = False):
     """Logits for a full sequence.
@@ -358,8 +389,7 @@ def forward(params: PyTree, cfg: ArchConfig, tokens: jnp.ndarray, *,
     vlm, S_text otherwise.
     """
     cdt = jnp.dtype(cfg.dtype)
-    emb = params["embed"].astype(cdt)
-    h = emb[tokens]
+    h = embed_lookup(params["embed"], tokens, cdt)
     enc_out = None
     if cfg.frontend == "vision" and frontend is not None:
         h = jnp.concatenate([frontend.astype(cdt), h], axis=1)

@@ -7,6 +7,8 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCHS
+from repro.kernels import ops as kernel_ops
+from repro.kernels.embed_grad import embed_grad
 from repro.models import transformer as tr
 from repro.launch.steps import make_train_step, make_serve_step
 
@@ -91,3 +93,60 @@ def test_serve_step(name, key):
     assert nxt.dtype == jnp.int32
     nxt2, _ = step(params, cache2, nxt, jnp.int32(1))
     assert np.isfinite(np.asarray(nxt2, np.float32)).all()
+
+
+def _cast_then_gather(table, tokens, dtype):
+    """The lookup as ``forward`` wrote it before ``embed_lookup``."""
+    return table.astype(dtype)[tokens]
+
+
+def _gather_then_cast(table, tokens, dtype):
+    """Autodiff of this form scatter-adds the rows into an f32 table."""
+    return table[tokens].astype(dtype)
+
+
+LOOKUP_NAMES = ["qwen1.5-4b", "command-r-35b"]     # dense; tied embeddings
+
+
+@pytest.mark.parametrize("name", LOOKUP_NAMES)
+def test_embed_lookup_forward_is_bit_identical(name, key, monkeypatch):
+    cfg = ARCHS[name].reduced(dtype="bfloat16")
+    params = tr.init_params(key, cfg)
+    tok = jax.random.randint(key, (2, 32), 0, cfg.vocab)
+
+    def logits():
+        return np.asarray(jax.jit(lambda p, t: tr.forward(p, cfg, t)[0])(
+            params, tok), np.float32)
+
+    new = logits()
+    monkeypatch.setattr(tr, "embed_lookup", _cast_then_gather)
+    np.testing.assert_array_equal(new, logits())
+
+
+@pytest.mark.parametrize("path", ["segment_sum", "kernel"])
+@pytest.mark.parametrize("repeated", [False, True])
+@pytest.mark.parametrize("name", LOOKUP_NAMES)
+def test_embed_grad_is_an_f32_sum(name, repeated, path, key, monkeypatch):
+    """The table's gradient through ``loss_fn`` is the f32 sum of the bf16
+    cotangent rows, as the f32 gather's own autodiff computes it; with
+    tied embeddings the head's gradient adds to it."""
+    cfg = ARCHS[name].reduced(dtype="bfloat16")
+    params = tr.init_params(key, cfg)
+    k1, k2 = jax.random.split(key)
+    tok = jax.random.randint(k1, (2, 32), 0, 5 if repeated else cfg.vocab)
+    lab = jax.random.randint(k2, (2, 32), 0, cfg.vocab)
+
+    def grad():
+        return np.asarray(jax.jit(jax.grad(tr.loss_fn), static_argnums=1)(
+            params, cfg, tok, lab)["embed"])
+
+    if path == "kernel":       # the TPU path, its kernel interpreted
+        monkeypatch.setattr(kernel_ops, "default_interpret", lambda: False)
+        monkeypatch.setattr(kernel_ops, "embed_grad",
+                            lambda *a: embed_grad(*a, interpret=True))
+    g = grad()
+    monkeypatch.setattr(tr, "embed_lookup", _gather_then_cast)
+    ref = grad()
+    assert g.dtype == np.float32
+    np.testing.assert_allclose(g, ref, rtol=1e-5,
+                               atol=1e-6 * np.abs(ref).max())

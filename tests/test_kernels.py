@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels.adel_agg import adel_agg, adel_agg_q8
+from repro.kernels.embed_grad import CHUNK, _block_rows, embed_grad
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ops import (adel_aggregate_pallas, gqa_flash,
                                ssd_chunked_pallas)
@@ -242,3 +243,56 @@ def test_adel_agg_pytree_matches_reference_path():
         np.testing.assert_allclose(np.asarray(out_k[k]),
                                    np.asarray(out_r[k]), rtol=2e-5,
                                    atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# embedding gradient (sorted segment sum into an f32 table)
+# ---------------------------------------------------------------------------
+
+def _edges(V, D, T):
+    """Ids on both sides of every vocabulary block's edge."""
+    bv = _block_rows(V, D, 2)
+    assert bv < V
+    e = np.arange(bv, V, bv)
+    return np.resize(np.stack([e - 1, e], 1).ravel(), T)
+
+
+EMBED_CASES = {
+    # name: (T, V, D, ids from (rng, T, V, D), clients)
+    "repeated_ids": (512, 1024, 256, lambda r, T, V, D: r.integers(0, 9, T),
+                     0),
+    "block_edges": (256, 4096, 640, lambda r, T, V, D: _edges(V, D, T), 0),
+    "empty_blocks": (256, 4096, 640,
+                     lambda r, T, V, D: np.where(r.random(T) < .5,
+                                                 r.integers(0, 50, T),
+                                                 r.integers(V - 50, V, T)),
+                     0),
+    "one_row": (384, 2048, 256, lambda r, T, V, D: np.full(T, 1500), 0),
+    "ragged_tail": (CHUNK * 2 + 37, 1024, 256,
+                    lambda r, T, V, D: r.integers(0, V, T), 0),
+    "d_not_512": (256, 2048, 640, lambda r, T, V, D: r.integers(0, V, T), 0),
+    "vmapped_clients": (200, 1024, 256,
+                        lambda r, T, V, D: r.integers(0, V, (3, T)), 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(EMBED_CASES))
+def test_embed_grad_matches_segment_sum(case):
+    """The kernel equals an f32 ``jax.ops.segment_sum`` of the bf16 rows."""
+    T, V, D, make_ids, U = EMBED_CASES[case]
+    rng = np.random.default_rng(7)
+    ids = jnp.sort(jnp.asarray(make_ids(rng, T, V, D), jnp.int32), axis=-1)
+    rows = _qs(ids.shape + (D,), 0, jnp.bfloat16)
+
+    def ref(i, r):
+        return jax.ops.segment_sum(r.astype(jnp.float32), i, num_segments=V)
+
+    def kern(i, r):
+        return embed_grad(i, r, V, interpret=True)
+
+    if U:
+        ref, kern = jax.vmap(ref), jax.vmap(kern)
+    out = kern(ids, rows)
+    assert out.shape == ids.shape[:-1] + (V, D) and out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref(ids, rows)),
+                               rtol=1e-6, atol=1e-6)

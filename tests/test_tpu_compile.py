@@ -5,11 +5,14 @@ its tiling, programs larger than the chip's memory), so these tests lower
 and compile for a described ``v5e:2x2`` topology and read the compiled
 program: the fold kernels at the real widths of one Qwen1.5-4B FFN leaf,
 the temporal round step at the one-chip share (``repro.configs.qwen1_5_4b``)
-and the shard_map round step over the four-chip mesh. Nothing runs.
+and the shard_map round step over the four-chip mesh, and in both the
+embedding gradient's kernel in place of XLA's scatter-add. Nothing runs.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -112,7 +115,10 @@ def test_temporal_round_step_fits_one_chip(one_chip, agg_impl, monkeypatch):
                                        agg_impl=agg_impl), 8,
                               one_chip, one_chip)
     assert _peak(compiled) < HBM_BYTES
-    assert ("tpu_custom_call" in compiled.as_text()) == (agg_impl == "pallas")
+    # the fold kernel engages exactly when asked for (the embedding
+    # gradient's kernel is in both)
+    text = compiled.as_text()
+    assert ('/adel_agg/pallas_call"' in text) == (agg_impl == "pallas")
 
 
 def test_shard_map_round_step_on_2x2_mesh(topo):
@@ -122,3 +128,29 @@ def test_shard_map_round_step_on_2x2_mesh(topo):
                               NamedSharding(mesh, P("data")))
     assert "all-reduce" in compiled.as_text()
     assert _peak(compiled) < HBM_BYTES          # bytes per device
+
+
+@pytest.mark.parametrize("layout,peak_bytes", [
+    ("temporal", 11.50e9),      # U=8 on one chip
+    ("shard_map", 14.80e9),     # U=8 over the 2x2, two clients a chip
+])
+def test_embedding_grad_is_the_kernel(topo, layout, peak_bytes, monkeypatch):
+    """The table gradient is the ``embed_grad`` kernel (one call on four
+    chips, whose vmap rule folds the clients into the vocabulary), and no
+    scatter writes a (k * V_pad, D) table; the peak stays in its bound."""
+    monkeypatch.setattr("repro.kernels.ops.default_interpret", lambda: False)
+    if layout == "temporal":
+        rep = cli = SingleDeviceSharding(topo.devices[0])
+        spec = ExecSpec(backend="temporal")
+    else:
+        mesh = Mesh(np.asarray(topo.devices), ("data",))
+        rep, cli = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+        spec = ExecSpec(backend="shard_map", mesh=mesh)
+    compiled = _compile_round(spec, 8, rep, cli)
+    text = compiled.as_text()
+    assert "/embed_grad/pallas_call" in text
+    cfg = get_config("qwen1.5-4b")
+    for dims in re.findall(r"= \w+\[([\d,]+)\][^ ]* scatter\(", text):
+        *_, rows, width = map(int, dims.split(","))
+        assert not (width == cfg.d_model and rows % cfg.padded_vocab == 0)
+    assert _peak(compiled) <= peak_bytes
