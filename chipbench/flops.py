@@ -1,33 +1,29 @@
 """Operations and bytes the work of a round requires, from shapes alone.
 
-Model FLOPs of one round (``round_flops``): for every active client u with
-``tok_u = min(S_u, s_max) * seq`` real tokens,
+Model FLOPs of one round (``round_flops``), from the per-token costs the
+configuration's family module gives (``costs``): for mask layer l the
+block's matmul parameters ``blk[l]`` and attention width ``att[l]``
+(H x (d_qk + d_v)), and the head's matmul parameters ``head``. For every
+active client u with ``tok_u = min(S_u, s_max) * seq`` real tokens and
+straggler mask ``m_u``, per token,
 
-* forward: 2 x matmul parameters per token, every projection and the LM
-  head over the held vocabulary slice (not the embedding gather, not the
-  padded vocabulary columns), plus attention 4 x L x heads x d_head x seq;
-* backward: 4 x matmul parameters per token and 8 x heads x d_head x seq of
-  attention, counted only through the layers the client's straggler mask
-  keeps (the depth-limited backprop of the paper): the blocks whose layer
-  is kept, and the head with the last layer.
+* forward: ``2 (sum_l blk[l] + head) + 2 seq sum_l att[l]``: every
+  projection and the head over the held vocabulary slice (not the
+  embedding gather, not the padded vocabulary columns), and attention's
+  two matmuls over ``seq`` positions;
+* backward: ``4 (m_u . blk + m_u[L-1] head) + 4 seq (m_u . att)``,
+  counted only through the layers the mask keeps (the depth-limited
+  backprop of the paper): the blocks whose layer is kept, and the head
+  with the last layer.
 
-So attention is 12 x L x heads x d_head x seq per token when every layer
-is kept, the usual count that does not halve causal attention.
+So attention is ``6 seq sum_l att[l]`` per token when every layer is
+kept, the usual count that does not halve causal attention.
 """
 from __future__ import annotations
 
 import numpy as np
 
-
-def block_matmul_params(a: dict) -> int:
-    """Matmul parameters of one dense GQA + SwiGLU block."""
-    D, H, KV = a["d_model"], a["n_heads"], a["n_kv"]
-    hd = a.get("d_head") or D // H
-    return D * H * hd + 2 * D * KV * hd + H * hd * D + 3 * D * a["d_ff"]
-
-
-def head_matmul_params(a: dict) -> int:
-    return a["d_model"] * a["vocab"]
+from chipbench import families
 
 
 def round_flops(a: dict, seq: int, batch: np.ndarray, mask: np.ndarray,
@@ -35,14 +31,11 @@ def round_flops(a: dict, seq: int, batch: np.ndarray, mask: np.ndarray,
     """Model FLOPs of one round: ``batch`` (U,) planned S_t^u of the active
     clients, ``mask`` (U, L) their straggler masks (column L-1 is the
     output layer, reached first by backprop)."""
-    L, H = a["L"], a["n_heads"]
-    hd = a.get("d_head") or a["d_model"] // H
-    blk, head = block_matmul_params(a), head_matmul_params(a)
-    att = H * hd * seq
+    blk, att, head = families.load(a).costs(a)
+    blk, att = np.asarray(blk, np.float64), np.asarray(att, np.float64)
+    L = blk.size
     tok = np.minimum(np.asarray(batch, np.float64), s_max) * seq      # (U,)
-    m = np.asarray(mask, np.float64)
-    kept = m.sum(1)                                                   # (U,)
-    fwd = 2.0 * (L * blk + head) + 4.0 * L * att
-    bwd = 4.0 * (kept * blk + m[:, L - 1] * head) + 8.0 * kept * att
+    m = np.asarray(mask, np.float64)                                  # (U, L)
+    fwd = 2.0 * (blk.sum() + head) + 2.0 * seq * att.sum()
+    bwd = 4.0 * (m @ blk + m[:, L - 1] * head) + 4.0 * seq * (m @ att)
     return float((tok * (fwd + bwd)).sum())
-

@@ -4,8 +4,10 @@ A cell of ``BENCHMARK.json`` names a configuration (``configs/<name>.json``:
 the ``ArchConfig`` fields as run, the source and the cut) and a traffic
 mix (``traffic/<name>.json``: cohort, row and plan sizes, eval cadence,
 the execution fields it sets). ``limits/<cell>.json`` holds the limit of
-each number the correctness check compares, and ``metrics/<name>.py`` one
-reader per per-layer metric. Nothing here names a cell.
+each number the correctness check compares, ``metrics/<name>.py`` one
+reader per per-layer metric, and ``families/<family>.py`` the parameter
+layout, forward pass, layer map and costs of the configuration's family.
+Nothing here names a cell or a leaf.
 
 A run:
 
@@ -180,14 +182,13 @@ class Bench:
         self._lm_eval = lm_eval_metrics
         shapes = jax.eval_shape(self.model.init,
                                 jax.ShapeDtypeStruct((2,), jnp.uint32))
-        ours = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s, jnp.float32),
-                            inputs.weight_shapes(self.a),
-                            is_leaf=lambda x: isinstance(x, tuple))
+        ours = jax.eval_shape(lambda: inputs.make_weights(self.a, 0))
         if jax.tree.structure(shapes) != jax.tree.structure(ours) or any(
                 (x.shape, x.dtype) != (y.shape, y.dtype) for x, y in
                 zip(jax.tree.leaves(shapes), jax.tree.leaves(ours))):
-            raise SystemExit("chipbench: the program's parameter layout is "
-                             "not the one inputs.weight_shapes writes down")
+            raise SystemExit(
+                "chipbench: the program's parameter layout is not the one "
+                f"families/{self.a['family']}.py writes down")
         self.n_params = sum(int(np.prod(x.shape))
                             for x in jax.tree.leaves(shapes))
         self.spec = ExecSpec(**tr["exec"])
@@ -270,7 +271,6 @@ class Bench:
         last, and its eval loss after each. Leaves the weights to the
         window."""
         k = self.cell.traffic["check_rounds"]
-        L = self.a["L"]
         rec = {"rounds": []}
 
         def capture(i, xb, eta, out):
@@ -283,7 +283,7 @@ class Bench:
             if i in (1, k):
                 w0 = inputs.make_weights(self.a, self.seed)
                 rec["grad1" if i == 1 else "change"] = \
-                    reference.change_norms(out, w0, L)
+                    reference.change_norms(out, w0, self.a)
                 del w0
 
         self.capture = capture
@@ -349,9 +349,7 @@ class Bench:
                              "step to read the compiled peak from")
         sds = jax.ShapeDtypeStruct
         L = self.model.L
-        params = jax.tree.map(lambda s: sds(s, jnp.float32),
-                              inputs.weight_shapes(self.a),
-                              is_leaf=lambda x: isinstance(x, tuple))
+        params = jax.eval_shape(lambda: inputs.make_weights(self.a, 0))
         args = (params, sds((self.U_pad, self.s_max, self.seq + 1), jnp.int32),
                 sds((self.U_pad, self.s_max), jnp.int32),
                 sds((self.U_pad, self.s_max), jnp.float32),
@@ -377,7 +375,6 @@ def reference_numbers(a: dict, seed: int, rec: dict,
     or a fault put in the program's place) over the checked rounds' inputs:
     its weight change after the first and last round, and its eval loss
     after each."""
-    L = a["L"]
     ref = reference.Reference(a, wire=wire, precision=precision)
     w = inputs.make_weights(a, seed)
     w0 = inputs.make_weights(a, seed)
@@ -387,8 +384,8 @@ def reference_numbers(a: dict, seed: int, rec: dict,
                       s_max, keep_rows=keep_rows)
         out["loss"].append(ref.eval_loss(w, eval_rows))
         if i == 1:
-            out["grad1"] = reference.change_norms(w, w0, L)
-    out["change"] = reference.change_norms(w, w0, L)
+            out["grad1"] = reference.change_norms(w, w0, a)
+    out["change"] = reference.change_norms(w, w0, a)
     del w, w0
     gc.collect()
     return out

@@ -12,25 +12,23 @@ One round, as the paper and the configuration state it:
    the clients that finished layer l (0 when none did), and
    ``w' = w - sum_u c[u, layer] * delta_u``, clients added in order.
 
-Layer l of the mask is block l; the embedding joins layer 0 and the final
-norm and LM head join layer L-1 (backprop reaches the output first).
-
-The transformer is written out here from the configuration: pre-norm
-blocks, RMSNorm, GQA with QKV bias, RoPE over all or the first half of
-each head (rotate-half pairing), causal softmax attention, SwiGLU, an
-untied head over the held vocabulary rows as stored (padding included,
-as the weights are given). Computed one client at a time, in float32 with
-``Precision.HIGHEST``. ``precision="fp8"`` is the control: every matmul
-operand, forward and backward, rounded to float8 e4m3 with a per-tensor
-scale.
+The layer of each leaf row, and the forward pass itself, are the
+configuration's family's (``families/<family>.py``: ``layer_map`` and
+``logits``); nothing here names a leaf. Computed one client at a time, in
+float32 with ``Precision.HIGHEST``. ``precision="fp8"`` is the control:
+every matmul operand, forward and backward, rounded to float8 e4m3 with a
+per-tensor scale.
 """
 from __future__ import annotations
 
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chipbench import families
 
 HIGHEST = jax.lax.Precision.HIGHEST
 EVAL_BLOCK = 8      # eval rows per call: the logits of 8 rows fit any cell
@@ -67,69 +65,43 @@ def make_mm(precision: str):
     raise ValueError(precision)
 
 
-def _rms(x, g, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g
-
-
-def _rope(x, mode: str, theta: float):
-    S, hd = x.shape[1], x.shape[-1]
-    rot = hd if mode == "full" else hd // 2
-    inv = 1.0 / theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-    x1, x2, rest = x[..., :rot // 2], x[..., rot // 2:rot], x[..., rot:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],
-                           -1)
-
-
-def logits(params, a: dict, tok, mm):
-    """(B, S) token ids -> (B, S, V_held) float32 logits."""
-    B, S = tok.shape
-    D, H, KV = a["d_model"], a["n_heads"], a["n_kv"]
-    hd = a.get("d_head") or D // H
-    G, eps = H // KV, a["norm_eps"]
-    h = params["embed"][tok]
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    for l in range(a["L"]):
-        p = jax.tree.map(lambda x: x[l], params["blocks"])
-        at = p["attn"]
-        x = _rms(h, p["norm1"], eps)
-        q = mm("bsd,df->bsf", x, at["wq"])
-        k = mm("bsd,df->bsf", x, at["wk"])
-        v = mm("bsd,df->bsf", x, at["wv"])
-        if "bq" in at:
-            q, k, v = q + at["bq"], k + at["bk"], v + at["bv"]
-        q = _rope(q.reshape(B, S, H, hd), a["rope_mode"], a["rope_theta"])
-        k = _rope(k.reshape(B, S, KV, hd), a["rope_mode"], a["rope_theta"])
-        k = jnp.repeat(k, G, axis=2)                 # head h reads kv h // G
-        v = jnp.repeat(v.reshape(B, S, KV, hd), G, axis=2)
-        s = mm("bqhd,bkhd->bhqk", q, k) * hd ** -0.5
-        s = jnp.where(causal, s, -1e30)
-        o = mm("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
-        h = h + mm("bsf,fd->bsd", o.reshape(B, S, H * hd), at["wo"])
-        x = _rms(h, p["norm2"], eps)
-        ml = p["mlp"]
-        f = jax.nn.silu(mm("bsd,df->bsf", x, ml["wg"])) * mm(
-            "bsd,df->bsf", x, ml["wu"])
-        h = h + mm("bsf,fd->bsd", f, ml["wd"])
-    h = _rms(h, params["final_norm"], eps)
-    return mm("bsd,dv->bsv", h, params["lm_head"])
-
-
 def row_nll(params, a: dict, rows, mm):
     """(B, S+1) rows -> (B,) mean next-token cross-entropy per row."""
-    lg = logits(params, a, rows[:, :-1], mm)
+    lg = families.load(a).logits(params, a, rows[:, :-1], mm)
     logp = jax.nn.log_softmax(lg, -1)
     nll = -jnp.take_along_axis(logp, rows[:, 1:, None], -1)[..., 0]
     return nll.mean(-1)
 
 
-def layer_of(path, L: int):
-    """Mask layer of a leaf: None for the stacked blocks (layer = index)."""
-    top = path[0].key
-    if top == "blocks":
-        return None
-    return 0 if top == "embed" else L - 1
+def row_layers(a: dict) -> dict:
+    """The family's layer map as 1-D int arrays: the mask layer of each row
+    of a leaf (one row for a leaf wholly in one layer)."""
+    return jax.tree.map(lambda lay: np.atleast_1d(np.asarray(lay, np.int32)),
+                        families.load(a).layer_map(a),
+                        is_leaf=lambda x: isinstance(x, tuple))
+
+
+def by_row(ids: np.ndarray, x):
+    """``x`` as ``(rows, -1)``, one row per entry of ``ids``."""
+    if ids.size > 1 and x.shape[0] != ids.size:
+        raise ValueError(f"a leaf of shape {x.shape} is mapped to "
+                         f"{ids.size} layer rows")
+    return x.reshape(ids.size, -1)
+
+
+def fold(acc, d, c_row, layers: dict, wire: str):
+    """``acc + c_row[layer] * d`` row by row of each leaf, with ``layers``
+    from ``row_layers``; under ``int8`` each row of ``d`` first takes its wire
+    form."""
+    def one(ids, acc_l, d_l):
+        x, c = by_row(ids, d_l), c_row[ids]
+        if wire == "int8":
+            amax = jnp.max(jnp.abs(x), -1)
+            inv = jnp.where(amax > 0, 127.0 / amax, 0.0)
+            x = jnp.rint(x * inv[:, None])
+            c = c * (amax / 127.0)
+        return acc_l + (c[:, None] * x).reshape(d_l.shape)
+    return jax.tree.map(one, layers, acc, d)
 
 
 def row_weights(batch: np.ndarray, s_max: int, rows: int) -> np.ndarray:
@@ -155,32 +127,17 @@ class Reference:
     def __init__(self, a: dict, *, wire: str = "none",
                  precision: str = "f32"):
         mm = make_mm(precision)
-        L = a["L"]
+        layers = row_layers(a)
 
         def delta(params, rows, w, eta):
             loss = lambda prm: jnp.sum(w * row_nll(prm, a, rows, mm))
             g = jax.grad(loss)(params)
             return jax.tree.map(lambda x, gg: x - (x - eta * gg), params, g)
 
-        def fold(acc, d, c_row):
-            def one(path, acc_l, d_l):
-                lay = layer_of(path, L)
-                if wire == "int8":
-                    rows_ = d_l.reshape(L if lay is None else 1, -1)
-                    amax = jnp.max(jnp.abs(rows_), -1)
-                    inv = jnp.where(amax > 0, 127.0 / amax, 0.0)
-                    q = jnp.rint(rows_ * inv[:, None])
-                    c = c_row if lay is None else c_row[lay][None]
-                    return acc_l + ((c * (amax / 127.0))[:, None]
-                                    * q).reshape(d_l.shape)
-                if lay is None:
-                    return acc_l + d_l * c_row.reshape(
-                        (L,) + (1,) * (d_l.ndim - 1))
-                return acc_l + d_l * c_row[lay]
-            return jax.tree_util.tree_map_with_path(one, acc, d)
-
         self._delta = jax.jit(delta)
-        self._fold = jax.jit(fold, donate_argnums=0)
+        self._fold = jax.jit(
+            lambda acc, d, c_row: fold(acc, d, c_row, layers, wire),
+            donate_argnums=0)
         self._step = jax.jit(lambda p, acc: jax.tree.map(jnp.subtract, p,
                                                          acc),
                              donate_argnums=0)
@@ -217,22 +174,24 @@ class Reference:
 
 
 @functools.lru_cache(maxsize=None)
-def _norms_fn(L: int):
+def _norms_fn(arch: str):
+    layers = row_layers(json.loads(arch))
+
     def norms(new, old):
-        def one(path, x, y):
-            d = (x.astype(jnp.float32) - y.astype(jnp.float32))
-            lay = layer_of(path, L)
-            d = d.reshape(L if lay is None else 1, -1)
+        def one(ids, x, y):
+            d = by_row(ids, x.astype(jnp.float32) - y.astype(jnp.float32))
             return jnp.sqrt(jnp.sum(d * d, -1))
-        return jax.tree_util.tree_map_with_path(one, new, old)
+        return jax.tree.map(one, layers, new, old)
     return jax.jit(norms)
 
 
-def change_norms(new, old, L: int) -> dict:
-    """Per (leaf, layer row) norm of ``new - old``: {"blocks/attn/wq/2": n}.
-    ``old`` is placed as ``new`` is (replicated over a mesh, say)."""
+def change_norms(new, old, a: dict) -> dict:
+    """Per (leaf, row) norm of ``new - old``, a row for each layer row of the
+    family's layer map: {"blocks/attn/wq/2": n}, the leaf's name alone for
+    a leaf of one row. ``old`` is placed as ``new`` is (replicated over a
+    mesh, say)."""
     old = jax.tree.map(lambda o, n: jax.device_put(o, n.sharding), old, new)
-    tree = jax.device_get(_norms_fn(L)(new, old))
+    tree = jax.device_get(_norms_fn(json.dumps(a, sort_keys=True))(new, old))
     out = {}
     for path, v in jax.tree_util.tree_flatten_with_path(tree)[0]:
         name = "/".join(str(k.key) for k in path)

@@ -2,12 +2,17 @@
 import json
 import re
 
+import jax
 import pytest
 
-from chipbench import harness, inputs
+from chipbench import families, harness
 
 BENCH = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+
+
+def is_shape(x):
+    return isinstance(x, tuple)
 
 
 def test_keys_and_names():
@@ -28,8 +33,21 @@ def test_cell_files(cell):
     # the configuration as run holds the published widths
     a = c.arch
     assert a["d_model"] and a["d_ff"] and a["n_heads"] % a["n_kv"] == 0
-    assert inputs.weight_shapes(a)["blocks"]["mlp"]["wg"] == (
-        a["L"], a["d_model"], a["d_ff"])
+    # its family's layer map gives every row of every leaf one mask layer,
+    # and every mask layer has its costs
+    fam = families.load(a)
+    blk, att, _ = fam.costs(a)
+    L = len(blk)
+    assert len(att) == L
+    shapes = jax.tree.leaves(fam.weight_shapes(a), is_leaf=is_shape)
+    layers = jax.tree.leaves(fam.layer_map(a), is_leaf=is_shape)
+    assert len(shapes) == len(layers)
+    seen = set()
+    for shape, lay in zip(shapes, layers):
+        ids = (lay,) if isinstance(lay, int) else lay
+        assert len(ids) == 1 or len(ids) == shape[0], (shape, lay)
+        seen |= set(ids)
+    assert seen == set(range(L))
     for m in c.per_layer:
         r = harness.reader(m["name"])
         assert (r.LAYER, r.UNIT, r.MOVES) == (m["layer"], m["unit"],

@@ -1,17 +1,20 @@
 """The FLOP and byte counts against counts made by hand."""
 import numpy as np
 
-from chipbench import flops
+from chipbench import families, flops
 
 # d_model 8, 2 query heads and 1 KV head of 4, d_ff 16, vocab 10, L 2
-A = {"L": 2, "d_model": 8, "n_heads": 2, "n_kv": 1, "d_head": 4, "d_ff": 16,
-     "vocab": 10}
+A = {"family": "dense", "L": 2, "d_model": 8, "n_heads": 2, "n_kv": 1,
+     "d_head": 4, "d_ff": 16, "vocab": 10}
 
 
 def test_block_and_head_params():
-    # wq 8x8 + wk, wv 8x4 each + wo 8x8 + 3 x 8x16
-    assert flops.block_matmul_params(A) == 64 + 32 + 32 + 64 + 384
-    assert flops.head_matmul_params(A) == 80
+    blk, att, head = families.load(A).costs(A)
+    # wq 8x8 + wk, wv 8x4 each + wo 8x8 + 3 x 8x16, in each of the 2 layers
+    assert list(blk) == [64 + 32 + 32 + 64 + 384] * 2
+    # 2 heads x (d_qk 4 + d_v 4)
+    assert list(att) == [2 * (4 + 4)] * 2
+    assert head == 80
 
 
 def test_round_flops_every_layer_kept():
